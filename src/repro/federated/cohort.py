@@ -70,6 +70,14 @@ def bucket_size(B: int, data_kind: str = "tokens") -> int:
     return 3 * p // 4 if 3 * p // 4 >= B else p
 
 
+def wave_axis(mesh, axis: Optional[str], Bp: int) -> Optional[str]:
+    """The mesh axis a wave of ``Bp`` bucketed members splits its cohort
+    axis over: ``axis`` when ``Bp`` divides that axis, else None — the wave
+    then runs on the mesh replicated (exact either way). The engine and the
+    batched client sketch both place a wave by this rule."""
+    return axis if axis is not None and Bp % mesh.shape[axis] == 0 else None
+
+
 class CohortEngine:
     """One compiled local-training step for a whole cohort.
 
@@ -114,7 +122,6 @@ class CohortEngine:
             ax = rules.mesh_axes(("cohort",))[0]
             if ax is not None and ax in mesh.axis_names:
                 self.cohort_axis = ax
-                self._axis_n = int(mesh.shape[ax])
             rep = NamedSharding(mesh, P())
             self.x = jax.device_put(self.x, rep)
             self.y = jax.device_put(self.y, rep)
@@ -264,10 +271,7 @@ class CohortEngine:
                 jnp.asarray(valid), jnp.asarray(counts),
                 jnp.asarray(lr_steps))
         if self.mesh is not None:
-            # shard the cohort axis when it divides the mesh; otherwise the
-            # wave still runs on the mesh, replicated (exact either way)
-            ax = (self.cohort_axis
-                  if self.cohort_axis and Bp % self._axis_n == 0 else None)
+            ax = wave_axis(self.mesh, self.cohort_axis, Bp)
             args = tuple(
                 jax.device_put(a, NamedSharding(
                     self.mesh, P(*([ax] + [None] * (a.ndim - 1)))))

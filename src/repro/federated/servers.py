@@ -39,7 +39,6 @@ from typing import Callable, List, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.common import sharding
@@ -427,10 +426,10 @@ class ShardedPolicyServer(PolicyServer):
             with sharding.param_axis(axis):
                 return raw(state, arr)
 
-        fn = jax.jit(shard_map(
+        fn = jax.jit(jax.shard_map(
             body, mesh=self.mesh,
             in_specs=(self._specs, _arrival_specs(axis, batched=False)),
-            out_specs=(self._specs, _INFO_SPECS), check_rep=False),
+            out_specs=(self._specs, _INFO_SPECS), check_vma=False),
             donate_argnums=(0,))
         _SHARDED_STEP_CACHE[key] = fn
         return fn
@@ -452,11 +451,11 @@ class ShardedPolicyServer(PolicyServer):
             with sharding.param_axis(axis):
                 return scan_many(state, arrs)
 
-        fn = jax.jit(shard_map(
+        fn = jax.jit(jax.shard_map(
             many, mesh=self.mesh,
             in_specs=(self._specs, _arrival_specs(axis, batched=True)),
             out_specs=(self._specs, _INFO_SPECS, P(None, axis)),
-            check_rep=False), donate_argnums=(0,))
+            check_vma=False), donate_argnums=(0,))
         _SHARDED_MANY_CACHE[key] = fn
         return fn
 

@@ -325,26 +325,41 @@ def _build_sketch_fn(cfg: ModelConfig, calib_batch: dict, psa_cfg: psa_lib.PSACo
 
 
 def make_sketch_fn_flat(cfg: ModelConfig, calib_batch: dict,
-                        psa_cfg: psa_lib.PSAConfig, spec: tu.FlatSpec):
+                        psa_cfg: psa_lib.PSAConfig, spec: tu.FlatSpec,
+                        mesh=None, axis: Optional[str] = None):
     return _memo_identity(
-        _SKETCH_FLAT_CACHE, (cfg, psa_cfg, spec), calib_batch,
-        lambda: _build_sketch_fn_flat(cfg, calib_batch, psa_cfg, spec))
+        _SKETCH_FLAT_CACHE, (cfg, psa_cfg, spec, mesh, axis), calib_batch,
+        lambda: _build_sketch_fn_flat(cfg, calib_batch, psa_cfg, spec,
+                                      mesh, axis))
 
 
 def _build_sketch_fn_flat(cfg: ModelConfig, calib_batch: dict,
-                          psa_cfg: psa_lib.PSAConfig, spec: tu.FlatSpec):
+                          psa_cfg: psa_lib.PSAConfig, spec: tu.FlatSpec,
+                          mesh=None, axis: Optional[str] = None):
     """Batched sketch over flat client models: (B, d) -> (B, k), one jitted
-    vmap call per wave (row counts bucketed like the engine)."""
+    vmap call per wave (row counts bucketed like the engine).
+
+    On a mesh the call runs under ``shard_map``, because the compiled Pallas
+    sketch kernel cannot be partitioned automatically: each device sketches
+    its rows of the cohort ``axis`` where the engine splits the wave
+    (``cohort.wave_axis``), else every device sketches all rows."""
     calib = {k: jnp.asarray(v) for k, v in calib_batch.items()}
     from repro.common.sharding import SINGLE_DEVICE_RULES as R
 
     def loss(params, batch):
         return model_lib.loss_fn(params, batch, cfg, R)
 
-    batched = jax.jit(jax.vmap(
+    rows = jax.vmap(
         lambda vec: psa_lib.client_sketch(loss, spec.unflatten(vec), calib,
-                                          psa_cfg)))
-    from repro.federated.cohort import bucket_size
+                                          psa_cfg))
+    if mesh is None:
+        batched = {None: jax.jit(rows)}
+    else:
+        from jax.sharding import PartitionSpec as P
+        batched = {ax: jax.jit(jax.shard_map(
+            rows, mesh=mesh, in_specs=P(ax), out_specs=P(ax),
+            check_vma=False)) for ax in {None, axis}}
+    from repro.federated.cohort import bucket_size, wave_axis
     data_kind = registry.get_family(cfg).data_kind
 
     def fn(w_stack: jnp.ndarray) -> jnp.ndarray:
@@ -354,7 +369,7 @@ def _build_sketch_fn_flat(cfg: ModelConfig, calib_batch: dict,
         if Bp > B:
             w_stack = jnp.concatenate(
                 [w_stack, jnp.zeros((Bp - B, w_stack.shape[1]), w_stack.dtype)])
-        return batched(w_stack)[:B]
+        return batched[wave_axis(mesh, axis, Bp)](w_stack)[:B]
 
     return fn
 
@@ -407,10 +422,14 @@ def make_digest_fn(d: int) -> Callable:
     and a jitted variant would recompile for every distinct wave size."""
     fn = _DIGEST_FN_CACHE.get(d)
     if fn is None:
-        probe = np.random.RandomState(_DIGEST_SEED).randn(d).astype(np.float32)
+        probe = np.random.RandomState(_DIGEST_SEED).randn(d).astype(
+            np.float32).astype(np.float64)
 
         def fn(rows):
-            rows = np.asarray(rows, np.float32)
+            # f32 rows, f64 sums: numpy orders a reduction's partial sums
+            # by the array's shape, which in f32 moved a row's digest by
+            # ~1e-5 between batches; in f64 the batch shows at ~1e-14
+            rows = np.asarray(rows, np.float32).astype(np.float64)
             return np.stack([np.sqrt(np.sum(rows * rows, axis=-1)),
                              rows @ probe], axis=-1)
 
@@ -839,7 +858,8 @@ def _drain_cohort(server, cfg, init_params, client_datasets, sim: SimConfig,
                       else None)
     sketch_flat = None
     if server.needs_sketch:
-        sketch_flat = make_sketch_fn_flat(cfg, calib_batch, psa_cfg, spec)
+        sketch_flat = make_sketch_fn_flat(cfg, calib_batch, psa_cfg, spec,
+                                          engine.mesh, engine.cohort_axis)
     unflatten = tu.jit_unflatten(spec) if receive_hook is not None else None
 
     next_eval = next_eval0

@@ -3,9 +3,9 @@
 One wave of B heterogeneous cohort members executes its dense layers as a
 single grouped matmul over the stacked member axis: ``lhs (G, M, K) @ rhs
 (G, K, N) -> (G, M, N)``, accumulated in f32 on the MXU. The per-group
-``valid`` mask turns ragged bucket padding into exact no-op rows — padded
-member slots emit exact zeros regardless of what garbage their padded
-params slab holds.
+``valid`` mask (held whole in SMEM, one scalar per group) turns ragged
+bucket padding into exact no-op rows — padded member slots emit exact zeros
+regardless of what garbage their padded params slab holds.
 
 Grid: ``(G, nm, nn, nk)`` with the contraction innermost so each (g, i, j)
 output tile is revisited across k-steps and accumulated in a VMEM f32
@@ -34,9 +34,19 @@ def _round_up(x: int, m: int) -> int:
     return -(-x // m) * m
 
 
+def _caller_precision():
+    """The caller's matmul precision, as far as Mosaic can express it: an
+    f32 contraction under ``jax.default_matmul_precision("float32")`` (or
+    ``"highest"``), else Mosaic's default MXU product — the same choice an
+    XLA dot (the ``"vmap"`` member path) makes under the same setting."""
+    p = jax.config.jax_default_matmul_precision
+    return (jax.lax.Precision.HIGHEST
+            if p is not None and p.lower() in ("float32", "highest") else None)
+
+
 def _grouped_matmul_kernel(valid_ref, lhs_ref, rhs_ref, out_ref, acc_ref,
-                           *, nk: int):
-    kk = pl.program_id(3)
+                           *, nk: int, precision):
+    g, kk = pl.program_id(0), pl.program_id(3)
 
     @pl.when(kk == 0)
     def _init():
@@ -45,11 +55,12 @@ def _grouped_matmul_kernel(valid_ref, lhs_ref, rhs_ref, out_ref, acc_ref,
     a = lhs_ref[0].astype(jnp.float32)            # (bm, bk)
     b = rhs_ref[0].astype(jnp.float32)            # (bk, bn)
     acc_ref[...] += jax.lax.dot_general(
-        a, b, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+        a, b, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32,
+        precision=precision)
 
     @pl.when(kk == nk - 1)
     def _done():
-        out_ref[0] = (acc_ref[...] * valid_ref[0, 0]).astype(out_ref.dtype)
+        out_ref[0] = (acc_ref[...] * valid_ref[g]).astype(out_ref.dtype)
 
 
 def grouped_matmul_pallas(lhs: jnp.ndarray, rhs: jnp.ndarray,
@@ -79,15 +90,16 @@ def grouped_matmul_pallas(lhs: jnp.ndarray, rhs: jnp.ndarray,
     lp = jnp.pad(lhs, [(0, 0), (0, Mp - M), (0, Kp - K)])
     rp = jnp.pad(rhs, [(0, 0), (0, Kp - K), (0, Np - N)])
     if valid is None:
-        v = jnp.ones((G, 1), jnp.float32)
+        v = jnp.ones((G,), jnp.float32)
     else:
-        v = valid.astype(jnp.float32).reshape(G, 1)
+        v = valid.astype(jnp.float32).reshape(G)
 
     out = pl.pallas_call(
-        functools.partial(_grouped_matmul_kernel, nk=nk),
+        functools.partial(_grouped_matmul_kernel, nk=nk,
+                          precision=_caller_precision()),
         grid=(G, nm, nn, nk),
         in_specs=[
-            pl.BlockSpec((1, 1), lambda g, i, j, kk: (g, 0)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec((1, bm, bk), lambda g, i, j, kk: (g, i, kk)),
             pl.BlockSpec((1, bk, bn), lambda g, i, j, kk: (g, kk, j)),
         ],

@@ -105,8 +105,6 @@ def run_one(arch: str, shape: str, mesh_kind: str, out_dir: str,
         compiled = lowered.compile()
         t_compile = time.time() - t0
         cost = compiled.cost_analysis() or {}
-        if isinstance(cost, (list, tuple)):  # jax>=0.4.30: one dict per device
-            cost = cost[0] if cost else {}
         try:
             mem = compiled.memory_analysis()
             mem_rec = {a: int(getattr(mem, a)) for a in (

@@ -36,6 +36,7 @@ from repro.data import (ClientDataset, dirichlet_partition,
 from repro.data.synthetic import SyntheticClassification
 from repro.federated import (SimConfig, SweepConfig, run_algorithm,
                              run_sweep, ALGORITHMS)
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import model as model_lib
 from repro.models import registry
 
@@ -140,6 +141,7 @@ def main():
     ap.add_argument("--out", default="artifacts/runs")
     args = ap.parse_args()
 
+    enable_compile_cache()
     mesh = None
     if args.mesh:
         from repro.launch.mesh import make_fed_mesh

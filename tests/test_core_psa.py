@@ -50,32 +50,40 @@ def test_sensitivity_second_order_approximates_zeroing():
     on a quadratic loss where the 2nd-order Taylor expansion is EXACT in the
     Hessian — the empirical-Fisher approximation is the only error source.
     Evaluated near the optimum (the regime the paper's sensitivity targets)
-    and averaged across seeds: a 6-point rank correlation is too coarse to
-    assert on a single draw."""
+    with the program's 4 Fisher microbatches of m = 16 samples. A microbatch
+    gradient's variance is sigma^2 H / m, so labels carry noise of sigma =
+    sqrt(m) = 4 for the empirical Fisher to estimate the Hessian, which
+    Eq. 8 assumes; without noise it is orders smaller and Eq. 8 reduces to
+    |g theta|. 24 parameters per draw (a 6-point rank correlation is too
+    coarse), averaged over 16 draws from numpy's seeded stream, so the
+    verdict does not hang on JAX's PRNG implementation."""
     def rank(a):
         order = np.argsort(a.ravel())
         r = np.empty_like(order)
         r[order] = np.arange(len(order))
         return r
 
+    n, micro, shape = 64, 4, (6, 4)
     corrs = []
-    for seed in range(8):
-        key = jax.random.PRNGKey(seed)
-        w_true = jax.random.normal(jax.random.fold_in(key, 2), (3, 2))
-        w = w_true + 0.3 * jax.random.normal(key, (3, 2))
-        params = {"w": w}
-        x = jax.random.normal(jax.random.fold_in(key, 1), (64, 3))
-        batch = {"x": x, "y": x @ w_true}
-        s = np.asarray(sensitivity(_quad_loss, params, batch, num_micro=4)["w"])
+    for seed in range(16):
+        rng = np.random.RandomState(seed)
+        w_true = rng.randn(*shape).astype(np.float32)
+        w = w_true + 0.3 * rng.randn(*shape).astype(np.float32)
+        x = rng.randn(n, shape[0]).astype(np.float32)
+        noise = np.sqrt(n // micro) * rng.randn(n, shape[1])
+        y = x @ w_true + noise.astype(np.float32)
+        params = {"w": jnp.asarray(w)}
+        batch = {"x": jnp.asarray(x), "y": jnp.asarray(y)}
+        s = np.asarray(sensitivity(_quad_loss, params, batch,
+                                   num_micro=micro)["w"])
 
         base = float(_quad_loss(params, batch))
         true = np.zeros_like(s)
-        for i in range(3):
-            for j in range(2):
-                wz = np.asarray(w).copy()
-                wz[i, j] = 0.0
-                true[i, j] = abs(
-                    base - float(_quad_loss({"w": jnp.asarray(wz)}, batch)))
+        for i, j in np.ndindex(*shape):
+            wz = w.copy()
+            wz[i, j] = 0.0
+            true[i, j] = abs(
+                base - float(_quad_loss({"w": jnp.asarray(wz)}, batch)))
         corrs.append(np.corrcoef(rank(s), rank(true))[0, 1])
     # the approximation must order parameters like the truth, on average
     assert np.mean(corrs) > 0.7, corrs

@@ -8,6 +8,7 @@ train under ``engine="cohort"`` end to end with trajectories pinned to the
 sequential oracle within 1e-5.
 """
 import dataclasses
+import types
 import warnings
 
 import jax
@@ -21,7 +22,7 @@ from repro.data import StackedClients, document_partition
 from repro.federated import SimConfig, run_algorithm
 from repro.federated import client as client_lib
 from repro.federated import simulator as sim_mod
-from repro.federated.cohort import CohortEngine, bucket_size
+from repro.federated.cohort import CohortEngine, bucket_size, wave_axis
 from repro.launch.train import build_task
 from repro.models import model as M
 from repro.models import registry
@@ -100,6 +101,15 @@ def test_bucket_size_grid():
     for b in range(1, 300):
         for kind in ("tokens", "image"):
             assert b <= bucket_size(b, kind) <= max(4, (3 * b + 1) // 2)
+
+
+def test_wave_axis_splits_only_divisible_buckets():
+    # the rule the cohort engine and the batched client sketch share
+    mesh = types.SimpleNamespace(shape={"data": 4})
+    assert wave_axis(mesh, "data", 8) == "data"
+    assert wave_axis(mesh, "data", 6) is None
+    assert wave_axis(mesh, None, 8) is None
+    assert wave_axis(None, None, 4) is None
 
 
 # ---------------------------------------------------------------------------

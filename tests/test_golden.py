@@ -158,6 +158,18 @@ def test_sharded_matches_golden(world, name, ndev):
     _check(_run(world, name, "cohort", mesh=make_fed_mesh(ndev)), _load(name))
 
 
+def test_digest_barely_depends_on_the_batch():
+    """A row's digest alone and inside a wave agree to f64 rounding, far
+    below the engines' tolerance (f32 sums differed by ~1e-5)."""
+    from repro.federated.simulator import make_digest_fn
+    rows = np.random.RandomState(0).randn(7, 100_003).astype(np.float32)
+    fn = make_digest_fn(rows.shape[1])
+    whole = fn(rows)
+    for i in range(len(rows)):
+        np.testing.assert_allclose(fn(rows[i:i + 1])[0], whole[i],
+                                   rtol=1e-12)
+
+
 def test_golden_digests_are_committed():
     """Every policy has its digest file (regen writes all seven at once)."""
     for name in POLICY_NAMES:

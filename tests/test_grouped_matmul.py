@@ -80,8 +80,11 @@ def test_kernel_compiled_matches_interpret():
     key = jax.random.PRNGKey(5)
     lhs = jax.random.normal(key, (3, 64, 192), jnp.float32)
     rhs = jax.random.normal(jax.random.fold_in(key, 1), (3, 192, 64), jnp.float32)
-    a = grouped_matmul_pallas(lhs, rhs, interpret=False)
-    b = grouped_matmul_pallas(lhs, rhs, interpret=True)
+    # the kernel follows the caller's matmul precision, as the interpreter's
+    # XLA dot does: under f32 both sides contract in f32
+    with jax.default_matmul_precision("float32"):
+        a = grouped_matmul_pallas(lhs, rhs, interpret=False)
+        b = grouped_matmul_pallas(lhs, rhs, interpret=True)
     _rel_close(a, b)
 
 

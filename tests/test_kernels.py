@@ -49,6 +49,23 @@ def test_sens_sketch_block_invariance():
                                    rtol=1e-4, atol=1e-4)
 
 
+def test_sens_sketch_under_vmap_matches_rows():
+    """The batched client-sketch path vmaps the kernel over a wave's members
+    (the batch axis becomes a leading grid axis): each row's sketch must
+    equal the unbatched kernel's on that row."""
+    key = jax.random.PRNGKey(4)
+    B, d = 3, 5000
+    theta, g = (jax.random.normal(jax.random.fold_in(key, i), (B, d))
+                for i in range(2))
+    f = jnp.abs(jax.random.normal(jax.random.fold_in(key, 2), (B, d)))
+    got = jax.vmap(lambda a, b, c: sens_sketch_pallas(
+        a, b, c, k=16, seed=2, block=2048, interpret=True))(theta, g, f)
+    for i in range(B):
+        want = ref.sens_sketch_ref(theta[i], g[i], f[i], k=16, seed=2)
+        np.testing.assert_allclose(np.asarray(got[i]), np.asarray(want),
+                                   rtol=1e-4, atol=1e-4)
+
+
 def test_sens_sketch_shards_compose_via_index_offset():
     """d-sharded contract: the sum of per-shard sketches computed with
     ``index_offset`` set to each shard's global start equals the full-vector

@@ -37,9 +37,8 @@ def test_roofline_chain_renders_nonempty_table(tmp_path):
                                       "internvl2-1b__train_4k__pod.json")))
     assert rec["status"] == "ok"
     assert rec["flops_per_device"] > 0
-    # xla_cost_analysis must be a flat dict (jax>=0.4.30 returns a list of
-    # per-device dicts from compiled.cost_analysis — the regression that
-    # left roofline with no ok artifacts to read)
+    # xla_cost_analysis must be a flat dict (what compiled.cost_analysis
+    # returns on the installed JAX; roofline reads it as one)
     assert isinstance(rec["xla_cost_analysis"], dict)
 
     r2 = _run(["-m", "benchmarks.roofline"], tmp_path)
