@@ -93,24 +93,12 @@ def log(msg: str) -> None:
     print(f"[chip_smoke] {msg}", flush=True)
 
 
-class CompileClock:
-    """Sums XLA backend-compile time from JAX's own monitoring events."""
-
-    EVENT = "/jax/core/compile/backend_compile_duration"
-
-    def __init__(self):
-        import jax
-        self.seconds = 0.0
-        self.count = 0
-        jax.monitoring.register_event_duration_secs_listener(self._on)
-
-    def _on(self, event, duration, **_):
-        if event == self.EVENT:
-            self.seconds += duration
-            self.count += 1
-
-    def mark(self):
-        return self.seconds, self.count
+def compiled() -> tuple:
+    """Backend compile seconds and programs built so far in this process:
+    the program's ``compile`` counter (``repro.common.obs``)."""
+    from repro.common import obs
+    c = obs.totals()["counters"].get(obs.COMPILE, {})
+    return c.get("seconds", 0.0), c.get("records", 0)
 
 
 def rel_err(got, want) -> float:
@@ -277,12 +265,12 @@ def run(world, horizon: float, engine: str = "cohort", mesh=None,
                          calib_batch=world["calib"])
 
 
-def timed_run(clock, label: str, world, horizon: float, **kw):
-    c0, n0 = clock.mark()
+def timed_run(label: str, world, horizon: float, **kw):
+    c0, n0 = compiled()
     t0 = time.perf_counter()
     res = run(world, horizon, **kw)
     wall = time.perf_counter() - t0
-    c1, n1 = clock.mark()
+    c1, n1 = compiled()
     info = {"engine": res.engine, "wall_s": wall, "compile_s": c1 - c0,
             "compiles": n1 - n0, "versions": res.versions,
             "dispatches": res.dispatches, "launched": res.launched,
@@ -339,10 +327,10 @@ def peak_bytes() -> dict:
             for d in jax.devices()}
 
 
-def one_chip(clock, summary: dict) -> None:
+def one_chip(summary: dict) -> None:
     world, summary["world"] = build_world(SAMPLES)
     summary["kernels"] = phase_kernels(world["cfg"], world["params"])
-    res, summary["run"] = timed_run(clock, "phase 3 cohort run", world,
+    res, summary["run"] = timed_run("phase 3 cohort run", world,
                                     HORIZON)
     check_main_run(res)
     summary["peak_bytes_in_use"] = peak_bytes()
@@ -350,9 +338,9 @@ def one_chip(clock, summary: dict) -> None:
     del world, res
     oracle, summary["oracle_world"] = build_world(ORACLE_SAMPLES)
     coh, summary["oracle_cohort"] = timed_run(
-        clock, "phase 4 cohort", oracle, ORACLE_HORIZON, record=True)
+        "phase 4 cohort", oracle, ORACLE_HORIZON, record=True)
     seq, summary["oracle_sequential"] = timed_run(
-        clock, "phase 4 sequential", oracle, ORACLE_HORIZON,
+        "phase 4 sequential", oracle, ORACLE_HORIZON,
         engine="sequential", record=True)
     if coh.versions <= 0:
         raise AssertionError("phase 4 horizon applied no aggregation")
@@ -360,7 +348,7 @@ def one_chip(clock, summary: dict) -> None:
     summary["oracle"] = compare(coh, seq, oracle, label)
     summary["controls"] = {}
     for name, psa in CONTROLS.items():
-        ctl, _ = timed_run(clock, f"phase 4 fault ({name})", oracle,
+        ctl, _ = timed_run(f"phase 4 fault ({name})", oracle,
                            ORACLE_HORIZON, record=True, psa=psa)
         summary["controls"][name] = compare(
             ctl, seq, oracle, f"phase 4 fault ({name}) vs sequential")
@@ -372,15 +360,15 @@ def one_chip(clock, summary: dict) -> None:
                                  f"limit {DIGEST_TOL}: the check is blind")
 
 
-def four_chips(clock, summary: dict) -> None:
+def four_chips(summary: dict) -> None:
     from repro.launch.mesh import make_fed_mesh
     world, summary["world"] = build_world(ORACLE_SAMPLES)
     sharded, summary["sharded"] = timed_run(
-        clock, "sharded run (4 chips)", world, ORACLE_HORIZON,
+        "sharded run (4 chips)", world, ORACLE_HORIZON,
         mesh=make_fed_mesh(4), record=True)
     check_main_run(sharded)
     single, summary["single"] = timed_run(
-        clock, "single-device run", world, ORACLE_HORIZON, record=True)
+        "single-device run", world, ORACLE_HORIZON, record=True)
     summary["peak_bytes_in_use"] = peak_bytes()
     log(f"peak device memory: {summary['peak_bytes_in_use']}")
     label = "sharded vs single device"
@@ -404,10 +392,11 @@ def main(argv=None) -> int:
         log("FAILED")
         return 1
     try:
-        clock = CompileClock()
-        (one_chip if args.chips == 1 else four_chips)(clock, summary)
+        c0, n0 = compiled()
+        (one_chip if args.chips == 1 else four_chips)(summary)
         summary["total_s"] = time.perf_counter() - t0
-        summary["compile_s"], summary["compiles"] = clock.mark()
+        c1, n1 = compiled()
+        summary["compile_s"], summary["compiles"] = c1 - c0, n1 - n0
     except Exception:
         traceback.print_exc()
         log("FAILED")
@@ -417,8 +406,11 @@ def main(argv=None) -> int:
         with open(os.path.join(OUT_DIR, f"chip_smoke_{args.chips}.json"),
                   "w") as f:
             json.dump(summary, f, indent=1, default=str)
+    from repro.common import obs
+    summary["obs"] = obs.totals()
     log(f"total {summary['total_s']:.1f}s, of which backend compile "
-        f"{summary['compile_s']:.1f}s in {summary['compiles']} programs")
+        f"{summary['compile_s']:.1f}s in {summary['compiles']} programs; "
+        f"the program's spans and counters:\n{obs.summary()}")
     print(json.dumps({"ok": True, "device": summary["device"]}))
     return 0
 

@@ -37,6 +37,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from repro.common import obs
 from repro.common import sharding
 from repro.common import tree as tu
 from repro.common.sharding import SINGLE_DEVICE_RULES
@@ -243,41 +244,61 @@ class CohortEngine:
         ``params_stack`` holds each member's dispatch snapshot (its anchor
         for prox/align); ``lrs``/``seeds`` are per-member, matching what the
         legacy loop would have used for that dispatch.
+
+        The host's part (schedules, padding, uploads, the compiled call's
+        enqueue) is the ``cohort.enqueue`` span; each call records one
+        ``cohort.wave``: ``members``, bucketed ``rows``, the members' real
+        local ``steps``, the ``schedule`` every row runs, and the real
+        ``samples`` those steps train on.
         """
-        B = int(params_stack.shape[0])
-        assert B >= 1
-        cids = np.asarray(cids, np.int32)
-        idx, valid, counts, nvalid = self._schedules(cids, np.asarray(seeds))
-        # per-(member, step) learning rate: the member's lr on real steps,
-        # 0 on padded steps (making them exact no-ops)
-        lr_steps = (np.asarray(lrs, np.float64)[:, None]
-                    * (nvalid > 0.0)).astype(np.float32)
-        Bp = bucket_size(B, self._data_kind)
-        if Bp > B:
+        with obs.span("cohort.enqueue"):
+            B = int(params_stack.shape[0])
+            assert B >= 1
+            cids = np.asarray(cids, np.int32)
+            idx, valid, counts, nvalid = self._schedules(cids,
+                                                         np.asarray(seeds))
+            # per-(member, step) learning rate: the member's lr on real
+            # steps, 0 on padded steps (making them exact no-ops)
+            lr_steps = (np.asarray(lrs, np.float64)[:, None]
+                        * (nvalid > 0.0)).astype(np.float32)
+            Bp = bucket_size(B, self._data_kind)
+            steps = self.steps_per_client[cids]
+            obs.record("cohort.wave", members=B, rows=Bp,
+                       steps=int(steps.sum()), schedule=self.num_steps,
+                       samples=int((steps * np.minimum(
+                           self.batch_size, self.sizes[cids])).sum()))
             pad = Bp - B
+            if pad > 0:
+                def padded(a):
+                    return np.concatenate(
+                        [a, np.zeros((pad,) + a.shape[1:], a.dtype)])
 
-            def padded(a):
-                return np.concatenate(
-                    [a, np.zeros((pad,) + a.shape[1:], a.dtype)])
+                params_stack = jnp.concatenate(
+                    [params_stack, jnp.zeros((pad, params_stack.shape[1]),
+                                             params_stack.dtype)])
+                idx, valid, lr_steps = map(padded, (idx, valid, lr_steps))
+                counts = np.concatenate(
+                    [counts, np.ones((pad,) + counts.shape[1:],
+                                     counts.dtype)])
+            deltas, w = self._launch(params_stack, cids, pad, idx, valid,
+                                     counts, lr_steps)
+            return deltas[:B], w[:B]
 
-            params_stack = jnp.concatenate(
-                [params_stack, jnp.zeros((pad, params_stack.shape[1]),
-                                         params_stack.dtype)])
-            cids, idx, valid, lr_steps = map(padded,
-                                             (cids, idx, valid, lr_steps))
-            counts = np.concatenate(
-                [counts, np.ones((pad,) + counts.shape[1:], counts.dtype)])
+    def _launch(self, params_stack, cids, pad, idx, valid, counts, lr_steps):
+        """The compiled wave over the resident slab, which each member
+        indexes by its client id (padded rows by client 0)."""
+        cids = np.concatenate([cids, np.zeros((pad,), cids.dtype)])
         args = (params_stack, jnp.asarray(cids), jnp.asarray(idx),
                 jnp.asarray(valid), jnp.asarray(counts),
                 jnp.asarray(lr_steps))
         if self.mesh is not None:
-            ax = wave_axis(self.mesh, self.cohort_axis, Bp)
+            ax = wave_axis(self.mesh, self.cohort_axis,
+                           int(params_stack.shape[0]))
             args = tuple(
                 jax.device_put(a, NamedSharding(
                     self.mesh, P(*([ax] + [None] * (a.ndim - 1)))))
                 for a in args)
-        deltas, w = self._run(self.x, self.y, *args)
-        return deltas[:B], w[:B]
+        return self._run(self.x, self.y, *args)
 
     def sweep_update(self, params_stack: jnp.ndarray, cids: Sequence[int],
                      lrs: Sequence[float], seeds_per_lane: np.ndarray
@@ -446,33 +467,12 @@ class StreamingCohortEngine(CohortEngine):
                 [y, jnp.zeros((pad,) + y.shape[1:], y.dtype)])
         return x, y
 
-    def cohort_update(self, params_stack: jnp.ndarray, cids: Sequence[int],
-                      lrs: Sequence[float], seeds: Sequence[int]
-                      ) -> Tuple[jnp.ndarray, jnp.ndarray]:
-        B = int(params_stack.shape[0])
-        assert B >= 1
-        cids = np.asarray(cids, np.int32)
-        idx, valid, counts, nvalid = self._schedules(cids, np.asarray(seeds))
-        lr_steps = (np.asarray(lrs, np.float64)[:, None]
-                    * (nvalid > 0.0)).astype(np.float32)
-        Bp = bucket_size(B, self._data_kind)
-        pad = Bp - B
+    def _launch(self, params_stack, cids, pad, idx, valid, counts, lr_steps):
+        """The compiled wave over the members' own streamed rows."""
         x, y = self._wave_rows(cids, pad)
-        if pad > 0:
-            def padded(a):
-                return np.concatenate(
-                    [a, np.zeros((pad,) + a.shape[1:], a.dtype)])
-
-            params_stack = jnp.concatenate(
-                [params_stack, jnp.zeros((pad, params_stack.shape[1]),
-                                         params_stack.dtype)])
-            idx, valid, lr_steps = map(padded, (idx, valid, lr_steps))
-            counts = np.concatenate(
-                [counts, np.ones((pad,) + counts.shape[1:], counts.dtype)])
-        deltas, w = self._run_rows(x, y, params_stack, jnp.asarray(idx),
-                                   jnp.asarray(valid), jnp.asarray(counts),
-                                   jnp.asarray(lr_steps))
-        return deltas[:B], w[:B]
+        return self._run_rows(x, y, params_stack, jnp.asarray(idx),
+                              jnp.asarray(valid), jnp.asarray(counts),
+                              jnp.asarray(lr_steps))
 
     def sweep_update(self, params_stack: jnp.ndarray, cids: Sequence[int],
                      lrs: Sequence[float], seeds_per_lane: np.ndarray

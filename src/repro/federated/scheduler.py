@@ -64,6 +64,7 @@ from typing import Optional
 
 import numpy as np
 
+from repro.common import obs
 from repro.federated.latency import (STREAM_AVAIL_DRAWS, _subseed,
                                      make_availability_trace,
                                      per_client_availability,
@@ -384,30 +385,34 @@ class Dispatcher:
                        avail_probs=streams.avail, data_sizes=data_sizes)
 
     def dispatch_many(self, ts, snaps=None, versions=None) -> None:
-        st = self.streams
-        n = len(ts)
-        ts = self.scheduler.launch_times(ts)
-        if versions is None:
-            versions = np.full(n, self.server.version, np.int64)
-        else:
-            versions = np.asarray(versions, np.int64)
-        cids = self.scheduler.select(ts, versions)
-        t_done = ts + st.latency.sample_for(cids)
-        if st.use_trace:
-            oks = st.trace.on_at(cids, ts)
-        elif st.use_avail:
-            oks = st.avail_rng.rand(n) < st.avail[cids]
-        else:
-            oks = np.ones(n, bool)
-        if snaps is None:
-            # (d,) flat vector (cohort), (S, d) lane stack (sweep), or the
-            # params pytree (sequential oracle) — shared by the whole batch
-            cur = self.server.flat_params if self.batched else self.server.params
-            snaps = [cur] * n
-        self.timeline.extend_arrays(t_done, np.arange(self.seq, self.seq + n),
-                                    cids, versions, oks, snaps)
-        self.seq += n
-        self.result.launched += n
+        with obs.span("dispatch"):
+            st = self.streams
+            n = len(ts)
+            ts = self.scheduler.launch_times(ts)
+            if versions is None:
+                versions = np.full(n, self.server.version, np.int64)
+            else:
+                versions = np.asarray(versions, np.int64)
+            cids = self.scheduler.select(ts, versions)
+            t_done = ts + st.latency.sample_for(cids)
+            if st.use_trace:
+                oks = st.trace.on_at(cids, ts)
+            elif st.use_avail:
+                oks = st.avail_rng.rand(n) < st.avail[cids]
+            else:
+                oks = np.ones(n, bool)
+            if snaps is None:
+                # (d,) flat vector (cohort), (S, d) lane stack (sweep), or
+                # the params pytree (sequential oracle) — shared by the
+                # whole batch
+                cur = (self.server.flat_params if self.batched
+                       else self.server.params)
+                snaps = [cur] * n
+            self.timeline.extend_arrays(
+                t_done, np.arange(self.seq, self.seq + n), cids, versions,
+                oks, snaps)
+            self.seq += n
+            self.result.launched += n
 
     def dispatch(self, t: float, snap=None, version=None) -> None:
         self.dispatch_many([t], None if snap is None else [snap],

@@ -41,6 +41,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from repro.common import obs
 from repro.common import sharding
 from repro.common import tree as tu
 from repro.core import psa as psa_lib
@@ -232,18 +233,29 @@ class PolicyServer:
             # largest power-of-two chunk so the jit cache stays O(log B)
             chunk = 1 << int(np.log2(B - off))
             sl = slice(off, off + chunk)
-            arrs = pol.Arrival(
-                update=deltas[sl], client_params=client_params[sl],
-                tau=jnp.asarray(v_dispatch[sl], jnp.float32),
-                client_id=jnp.asarray(ids[sl], jnp.int32),
-                data_size=jnp.asarray(data_sizes[sl], jnp.float32),
-                sketch=sketches[sl])
-            state, infos, snaps = self._step_many(state, arrs)
-            if self.policy.log_fn is None:
-                # only the update flags cross to the host (one sync, not six)
-                infos = infos._replace(updated=np.asarray(infos.updated))
-            else:
-                infos = jax.tree_util.tree_map(np.asarray, infos)
+            with obs.span("ingest.enqueue"):
+                arrs = pol.Arrival(
+                    update=deltas[sl], client_params=client_params[sl],
+                    tau=jnp.asarray(v_dispatch[sl], jnp.float32),
+                    client_id=jnp.asarray(ids[sl], jnp.int32),
+                    data_size=jnp.asarray(data_sizes[sl], jnp.float32),
+                    sketch=sketches[sl])
+                state, infos, snaps = self._step_many(state, arrs)
+            # the host waits for each chunk before it enqueues the next, so
+            # that the device has freed a chunk's inputs before the next
+            # chunk's are made: enqueueing every chunk first holds them all
+            # at once and raises the peak memory
+            with obs.span("ingest.wait"):
+                jax.block_until_ready(infos)
+            with obs.span("ingest.readback"):
+                if self.policy.log_fn is None:
+                    # only the update flags cross to the host (one copy,
+                    # not six)
+                    infos = infos._replace(updated=np.asarray(infos.updated))
+                else:
+                    infos = jax.tree_util.tree_map(np.asarray, infos)
+            obs.record("ingest.chunk", arrivals=chunk,
+                       aggregations=int(np.sum(infos.updated)))
             infos_parts.append(infos)
             snap_parts.append(snaps)
             off += chunk
@@ -251,25 +263,26 @@ class PolicyServer:
         updated = np.concatenate([p.updated.reshape(-1) for p in infos_parts])
         snapshots = (snap_parts[0] if len(snap_parts) == 1
                      else jnp.concatenate(snap_parts))
-        taus: List[int] = []
-        v = self._version
-        row = 0
-        for part in infos_parts:
-            for i in range(part.updated.shape[0]):
-                tau = v - int(v_dispatch[row])
-                taus.append(tau)
-                if part.updated[i]:
-                    v += 1
-                    if self.policy.log_fn is not None:
-                        info_row = pol.StepInfo(*[np.asarray(f)[i]
-                                                  for f in part])
-                        meta = {"tau": tau, "client_id": int(ids[row]),
-                                "data_size": float(data_sizes[row])}
-                        entry = self.policy.log_fn(info_row, meta)
-                        if entry is not None:
-                            self.log.append(entry)
-                row += 1
-        self._version = v
+        with obs.span("ingest.log"):
+            taus: List[int] = []
+            v = self._version
+            row = 0
+            for part in infos_parts:
+                for i in range(part.updated.shape[0]):
+                    tau = v - int(v_dispatch[row])
+                    taus.append(tau)
+                    if part.updated[i]:
+                        v += 1
+                        if self.policy.log_fn is not None:
+                            info_row = pol.StepInfo(*[np.asarray(f)[i]
+                                                      for f in part])
+                            meta = {"tau": tau, "client_id": int(ids[row]),
+                                    "data_size": float(data_sizes[row])}
+                            entry = self.policy.log_fn(info_row, meta)
+                            if entry is not None:
+                                self.log.append(entry)
+                    row += 1
+            self._version = v
         return updated, taus, self._strip_stack(snapshots)
 
     def _receive_many_fallback(self, deltas, client_params, ids, data_sizes,

@@ -39,6 +39,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.common import obs
 from repro.common import tree as tu
 from repro.core import psa as psa_lib
 from repro.data.loader import ClientDataset, ClientSlabStore, StackedClients
@@ -366,6 +367,7 @@ def _build_sketch_fn_flat(cfg: ModelConfig, calib_batch: dict,
         B = int(w_stack.shape[0])
         # same family-dependent bucket grid as the engine
         Bp = bucket_size(B, data_kind)
+        obs.record("sketch.rows", rows=Bp)
         if Bp > B:
             w_stack = jnp.concatenate(
                 [w_stack, jnp.zeros((Bp - B, w_stack.shape[1]), w_stack.dtype)])
@@ -848,6 +850,11 @@ def _drain_cohort(server, cfg, init_params, client_datasets, sim: SimConfig,
     latency_lo`` — and at an equal timestamp sorts after the wave by ``seq``
     — so training the wave up front observes exactly the snapshots, learning
     rates, and seeds the sequential engine would have used.
+
+    Each wave is a ``sim.wave`` span (``repro.common.obs``) holding its
+    ``sim.assemble`` (timeline pops), ``sim.gather`` (snapshot stacking),
+    ``sketch.enqueue`` and ``eval`` spans, besides those of the engine, the
+    server and the dispatcher it calls.
     """
     spec = server.policy.spec
     engine = _make_cohort_engine(cfg, client_datasets, spec, init_params,
@@ -865,123 +872,133 @@ def _drain_cohort(server, cfg, init_params, client_datasets, sim: SimConfig,
     next_eval = next_eval0
     t = t0
     while timeline and t < sim.horizon:
-        if ckpt is not None:
-            ckpt(timeline, t, next_eval)
-        first = timeline.pop()
-        if first.t_done > sim.horizon:
-            t = first.t_done       # mirror the sequential pop-then-break
-            break
-        bound = first.t_done + sim.latency_lo
-        wave: List[_Event] = [first]
-        t_over = None
-        while (timeline and timeline.head_t() < bound
-               and len(wave) < sim.max_cohort):
-            ev = timeline.pop()
-            if ev.t_done > sim.horizon:
-                t_over = ev.t_done  # discarded, like the sequential break
-                break
-            wave.append(ev)
+        with obs.span("sim.wave"):
+            if ckpt is not None:
+                ckpt(timeline, t, next_eval)
+            with obs.span("sim.assemble"):
+                first = timeline.pop()
+                if first.t_done > sim.horizon:
+                    t = first.t_done   # mirror the sequential pop-then-break
+                    break
+                bound = first.t_done + sim.latency_lo
+                wave: List[_Event] = [first]
+                t_over = None
+                while (timeline and timeline.head_t() < bound
+                       and len(wave) < sim.max_cohort):
+                    ev = timeline.pop()
+                    if ev.t_done > sim.horizon:
+                        # discarded, like the sequential break
+                        t_over = ev.t_done
+                        break
+                    wave.append(ev)
 
-        ok_events = [ev for ev in wave if ev.ok]
-        deltas = w_stack = sketches = None
-        if ok_events:
-            d0 = result.dispatches
-            snapshots = _gather_snapshots([ev.snapshot for ev in ok_events])
-            cids = [ev.cid for ev in ok_events]
-            lrs = [sim.lr * (sim.lr_decay ** (d0 + r))
-                   for r in range(len(ok_events))]
-            seeds = [sim.seed * 100003 + (d0 + r)
-                     for r in range(len(ok_events))]
-            deltas, w_stack = engine.cohort_update(snapshots, cids, lrs, seeds)
-            if sketch_flat is not None:
-                sketches = sketch_flat(w_stack)
-            result.cohorts += 1
+            ok_events = [ev for ev in wave if ev.ok]
+            deltas = w_stack = sketches = None
+            if ok_events:
+                d0 = result.dispatches
+                with obs.span("sim.gather"):
+                    snapshots = _gather_snapshots(
+                        [ev.snapshot for ev in ok_events])
+                cids = [ev.cid for ev in ok_events]
+                lrs = [sim.lr * (sim.lr_decay ** (d0 + r))
+                       for r in range(len(ok_events))]
+                seeds = [sim.seed * 100003 + (d0 + r)
+                         for r in range(len(ok_events))]
+                deltas, w_stack = engine.cohort_update(snapshots, cids, lrs,
+                                                       seeds)
+                if sketch_flat is not None:
+                    with obs.span("sketch.enqueue"):
+                        sketches = sketch_flat(w_stack)
+                result.cohorts += 1
 
-        # Receives are deferred into ``pending`` and flushed as ONE batched
-        # ingest (``receive_many``) — flushing early only when an eval
-        # boundary needs the intermediate global model, or per-event when a
-        # receive_hook must observe pre-receive server state. Replacement
-        # dispatches happen inside the flush, each snapshotting the global
-        # vector as of *its* event (``snaps`` rows), so RNG order and
-        # snapshot contents match the sequential engine exactly.
-        pending: List[_Event] = []
-        next_row = 0
+            # Receives are deferred into ``pending`` and flushed as ONE batched
+            # ingest (``receive_many``) — flushing early only when an eval
+            # boundary needs the intermediate global model, or per-event when a
+            # receive_hook must observe pre-receive server state. Replacement
+            # dispatches happen inside the flush, each snapshotting the global
+            # vector as of *its* event (``snaps`` rows), so RNG order and
+            # snapshot contents match the sequential engine exactly.
+            pending: List[_Event] = []
+            next_row = 0
 
-        def flush():
-            nonlocal next_row
-            if not pending:
-                return
-            ok = [ev for ev in pending if ev.ok]
-            r0, r1 = next_row, next_row + len(ok)
-            cur = server.flat_params   # pre-flush vector, for leading dropouts
-            snaps = None
-            upd = np.zeros((0,), bool)
-            if ok:
+            def flush():
+                nonlocal next_row
+                if not pending:
+                    return
+                ok = [ev for ev in pending if ev.ok]
+                r0, r1 = next_row, next_row + len(ok)
+                # pre-flush vector, for leading dropouts
+                cur = server.flat_params
+                snaps = None
+                upd = np.zeros((0,), bool)
+                if ok:
+                    if receive_hook is not None:
+                        assert len(pending) == 1
+                        ev = ok[0]
+                        meta = {"tau": server.version - ev.version,
+                                "client_id": ev.cid,
+                                "data_size": float(data_sizes[ev.cid])}
+                        if sketches is not None:
+                            meta["sketch"] = sketches[r0]
+                        receive_hook(server, unflatten(w_stack[r0]),
+                                     unflatten(deltas[r0]), meta, ev.t_done)
+                    upd, taus, snaps = server.receive_many(
+                        deltas[r0:r1], w_stack[r0:r1],
+                        [ev.cid for ev in ok],
+                        [float(data_sizes[ev.cid]) for ev in ok],
+                        [ev.version for ev in ok],
+                        None if sketches is None else sketches[r0:r1])
+                    if digest_fn is not None:
+                        result.digests.extend(digest_fn(snaps).tolist())
+                    for ev, tau in zip(ok, taus):
+                        result.receive_log.append(
+                            {"t": ev.t_done, "tau": tau, "client": ev.cid})
+                    result.dispatches += len(ok)
+                    next_row = r1
+                vcur = server.version - int(np.sum(upd))  # version pre-flush
+                oi = 0
+                # replacement dispatches batched as ONE run insertion; each
+                # snapshots the global vector as of *its* event (snaps rows)
+                ts_, snaps_, vers_ = [], [], []
+                for ev in pending:
+                    if ev.ok:
+                        cur = (snaps, oi)   # row reference, gathered lazily
+                        vcur += int(upd[oi])
+                        oi += 1
+                    else:
+                        result.dropped += 1
+                    ts_.append(ev.t_done)
+                    snaps_.append(cur)
+                    vers_.append(vcur)
+                dispatch_many(ts_, snaps_, vers_)
+                pending.clear()
+
+            for ev in wave:
+                t = ev.t_done
+                if next_eval <= t:
+                    flush()
+                    while next_eval <= t:
+                        with obs.span("eval"):
+                            acc = evaluate(server.params)
+                        result.times.append(next_eval)
+                        result.accuracies.append(acc)
+                        next_eval += sim.eval_every
+                pending.append(ev)
                 if receive_hook is not None:
-                    assert len(pending) == 1
-                    ev = ok[0]
-                    meta = {"tau": server.version - ev.version,
-                            "client_id": ev.cid,
-                            "data_size": float(data_sizes[ev.cid])}
-                    if sketches is not None:
-                        meta["sketch"] = sketches[r0]
-                    receive_hook(server, unflatten(w_stack[r0]),
-                                 unflatten(deltas[r0]), meta, ev.t_done)
-                upd, taus, snaps = server.receive_many(
-                    deltas[r0:r1], w_stack[r0:r1],
-                    [ev.cid for ev in ok],
-                    [float(data_sizes[ev.cid]) for ev in ok],
-                    [ev.version for ev in ok],
-                    None if sketches is None else sketches[r0:r1])
-                if digest_fn is not None:
-                    result.digests.extend(digest_fn(snaps).tolist())
-                for ev, tau in zip(ok, taus):
-                    result.receive_log.append(
-                        {"t": ev.t_done, "tau": tau, "client": ev.cid})
-                result.dispatches += len(ok)
-                next_row = r1
-            vcur = server.version - int(np.sum(upd))  # version pre-flush
-            oi = 0
-            # replacement dispatches batched as ONE run insertion; each
-            # snapshots the global vector as of *its* event (snaps rows)
-            ts_, snaps_, vers_ = [], [], []
-            for ev in pending:
-                if ev.ok:
-                    cur = (snaps, oi)   # row reference, gathered lazily
-                    vcur += int(upd[oi])
-                    oi += 1
-                else:
-                    result.dropped += 1
-                ts_.append(ev.t_done)
-                snaps_.append(cur)
-                vers_.append(vcur)
-            dispatch_many(ts_, snaps_, vers_)
-            pending.clear()
-
-        for ev in wave:
-            t = ev.t_done
-            if next_eval <= t:
-                flush()
-                while next_eval <= t:
-                    acc = evaluate(server.params)
-                    result.times.append(next_eval)
-                    result.accuracies.append(acc)
-                    next_eval += sim.eval_every
-            pending.append(ev)
-            if receive_hook is not None:
-                flush()
-        flush()
-        # the wave's replacements are inserted: the NEXT wave's member set
-        # is determined, so overlap its materialization + upload with the
-        # still-retiring device work (device dispatch is async)
-        if prefetch_store is not None and t_over is None and t < sim.horizon:
-            nxt = timeline.peek_wave_cids(sim.latency_lo, sim.max_cohort,
-                                          sim.horizon)
-            if nxt.size:
-                prefetch_store.prefetch(nxt)
-        if t_over is not None:
-            t = t_over
-            break
+                    flush()
+            flush()
+            # the wave's replacements are inserted: the NEXT wave's member set
+            # is determined, so overlap its materialization + upload with the
+            # still-retiring device work (device dispatch is async)
+            if (prefetch_store is not None and t_over is None
+                    and t < sim.horizon):
+                nxt = timeline.peek_wave_cids(sim.latency_lo, sim.max_cohort,
+                                              sim.horizon)
+                if nxt.size:
+                    prefetch_store.prefetch(nxt)
+            if t_over is not None:
+                t = t_over
+                break
     return t
 
 

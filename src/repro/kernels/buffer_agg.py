@@ -69,6 +69,7 @@ def buffer_agg_pallas(weights: jnp.ndarray, global_vec: jnp.ndarray,
         ],
         out_specs=pl.BlockSpec((r, LANES), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((rows, LANES), jnp.float32),
+        name="buffer_agg",
         interpret=interpret,
     )(weights.astype(jnp.float32), gv.reshape(rows, LANES),
       up.reshape(L, rows, LANES))

@@ -106,6 +106,7 @@ def grouped_matmul_pallas(lhs: jnp.ndarray, rhs: jnp.ndarray,
         out_specs=pl.BlockSpec((1, bm, bn), lambda g, i, j, kk: (g, i, j)),
         out_shape=jax.ShapeDtypeStruct((G, Mp, Np), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
+        name="grouped_matmul",
         interpret=interpret,
     )(v, lp, rp)
     return out[:, :M, :N]

@@ -109,6 +109,7 @@ def sens_sketch_pallas(theta: jnp.ndarray, g: jnp.ndarray, f: jnp.ndarray,
         in_specs=[pl.BlockSpec((rows, LANES), lambda i: (i, 0))] * 3,
         out_specs=pl.BlockSpec((1, k), lambda i: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((1, k), jnp.float32),
+        name="sens_sketch",
         interpret=interpret,
     )(theta, g, f)
     return out[0] / jnp.sqrt(jnp.float32(k))

@@ -58,31 +58,38 @@ def one_chip(v5e_2x2):
     return SingleDeviceSharding(v5e_2x2[0])
 
 
-def _compile_to_mosaic(fn, sharding, *shapes):
+def _compile_to_mosaic(fn, name, sharding, *shapes):
+    """Compile ``fn``; it must hold a Mosaic kernel, and that kernel must
+    carry its stable ``name`` (the instruction a profiler trace shows)."""
     args = [jax.ShapeDtypeStruct(s, jnp.float32, sharding=sharding)
             for s in shapes]
     text = jax.jit(fn).lower(*args).compile().as_text()
-    assert "tpu_custom_call" in text
+    kernels = [line for line in text.splitlines()
+               if 'custom_call_target="tpu_custom_call"' in line]
+    assert kernels
+    assert all(line.split("=")[0].split()[-1].startswith(f"%{name}")
+               for line in kernels)
 
 
 @pytest.mark.parametrize("L,d", [(5, D_CNN), (5, 10)])
 def test_buffer_agg_compiles(one_chip, L, d):
     _compile_to_mosaic(functools.partial(buffer_agg_pallas, interpret=False),
-                       one_chip, (L,), (d,), (L, d))
+                       "buffer_agg", one_chip, (L,), (d,), (L, d))
 
 
 @pytest.mark.parametrize("d", [D_FC0, 10])
 def test_sens_sketch_compiles(one_chip, d):
     _compile_to_mosaic(functools.partial(sens_sketch_pallas, k=16, seed=3,
                                          interpret=False),
-                       one_chip, (d,), (d,), (d,))
+                       "sens_sketch", one_chip, (d,), (d,), (d,))
 
 
 def test_grouped_matmul_compiles(one_chip):
     G, M, K, N = 4, 64, 4096, 384       # fc0 at batch 64, a 4-member bucket
     _compile_to_mosaic(functools.partial(grouped_matmul_pallas,
                                          interpret=False),
-                       one_chip, (G, M, K), (G, K, N), (G,))
+                       "grouped_matmul", one_chip, (G, M, K), (G, K, N),
+                       (G,))
 
 
 @pytest.mark.parametrize("axis", ["d", None])
