@@ -5,8 +5,8 @@ loop of per-batch jit calls on pytrees — every simulated dispatch pays
 O(epochs * batches) device-call overhead plus a pytree snapshot. This module
 replaces it with ONE compiled call per *cohort*: all clients whose
 completions drain together train simultaneously via ``vmap`` over the cohort
-axis and ``lax.scan`` over their local SGD steps, operating directly on the
-flat ``(d,)`` parameter layout from ``common.tree.FlatSpec`` (no pytree
+axis and a ``lax.fori_loop`` over their local SGD steps, operating directly on
+the flat ``(d,)`` parameter layout from ``common.tree.FlatSpec`` (no pytree
 unflatten on the host — ``spec.unflatten`` happens inside the traced loss).
 
 Data lives on device once, as a padded ``(C, n_max, ...)`` slab
@@ -15,14 +15,14 @@ Data lives on device once, as a padded ``(C, n_max, ...)`` slab
 schedules come from the same ``epoch_batch_indices`` stream the legacy
 iterator uses, so the engine reproduces the per-client loop's arithmetic to
 float tolerance — ragged client sizes are handled by masking batch tails
-inside the loss, and padded scan steps / padded cohort rows are exact no-ops.
+inside the loss, and padded steps / padded cohort rows are exact no-ops.
 
 The member loss is model-agnostic: it comes from the family registry
 (``models.registry.get_family(cfg).client_loss`` with the mask folded in by
 ``masked_batch``), so ANY registered family — the paper's cnn/mlp, the
 dense/ssm/moe/hybrid LM families via ``model_lib.loss_fn`` (remat honored
 per ``ModelConfig``), or a user-registered one — compiles into the same
-vmap x scan program.
+vmap x loop program.
 
 FedProx (``prox``) and FedPAC (``align``) fold in as static config: the
 proximal/alignment pulls are plain vector arithmetic on the flat layout
@@ -84,9 +84,11 @@ class CohortEngine:
 
     Built once per (model, stacked data, epochs, batch_size, prox, align);
     ``cohort_update`` then costs one device call per cohort. Cohort sizes
-    are bucketed to the ``bucket_size`` grid and scan length is fixed at
-    the global maximum, so the jit cache holds O(log C) programs, not one
-    per cohort shape.
+    are bucketed to the ``bucket_size`` grid and the schedule arrays keep
+    the engine-wide ``num_steps`` frame, so the jit cache holds one program
+    per row bucket, not one per cohort shape. The local-SGD loop's trip
+    count is a traced argument: each wave stops at its longest member's
+    last real step, and one compiled program serves every trip count.
     """
 
     def __init__(self, cfg: ModelConfig, stacked: StackedClients,
@@ -126,8 +128,9 @@ class CohortEngine:
             rep = NamedSharding(mesh, P())
             self.x = jax.device_put(self.x, rep)
             self.y = jax.device_put(self.y, rep)
-        # Per-client steps/epoch under the drop-last rule; the scan runs the
-        # global max and masks the tail (a masked step is an exact no-op).
+        # Per-client steps/epoch under the drop-last rule; a wave's loop runs
+        # its longest member's count and masks the shorter members' tails (a
+        # masked step is an exact no-op).
         bs_c = np.minimum(self.batch_size, self.sizes)
         self.steps_per_client = (self.local_epochs * (self.sizes // bs_c)).astype(int)
         self.num_steps = int(self.steps_per_client.max())
@@ -146,16 +149,16 @@ class CohortEngine:
     # -- compiled core ------------------------------------------------------
 
     @staticmethod
-    def _build(cfg, spec, prox, align, fam, member_kernel="vmap"):
-        def member(x_all, y_all, p0_flat, cid, idx, valid, counts, lr_steps):
+    def _local_sgd(cfg, spec, prox, align, fam, member_kernel="vmap"):
+        """One member's local training on its own rows ``xs``/``ys``: the
+        program both engines vmap over their cohort axis."""
+        def member(xs, ys, p0_flat, idx, valid, counts, lr_steps, n_steps):
           # member-math routing is a trace-time switch: "grouped" makes the
           # vmap over members collapse every dense layer into one Pallas
           # grouped-GEMM launch (models.member_math); "vmap" keeps the exact
           # per-member dot_general HLO the golden digests pin.
           with member_math.routing(member_kernel):
-            xs = x_all[cid]          # (n_max, ...) this member's data
-            ys = y_all[cid]
-            # The scan carries the params *pytree*: unflatten/flatten happen
+            # The loop carries the params *pytree*: unflatten/flatten happen
             # once at the boundary, not (with their grad-transpose scatters)
             # inside every local step — the per-step program stays the same
             # op sequence the legacy per-batch jit ran.
@@ -178,38 +181,55 @@ class CohortEngine:
             # 0 on padded steps) are host-precomputed so the compiled step
             # carries no mask bookkeeping; a padded step has finite g (safe
             # denominator) and lr_t = 0 — an exact no-op.
-            def body(p, sl):
-                bi, vm, cnt, lr_t = sl
-                g = grad(p, xs[bi], ys[bi], vm, cnt)
-                p = jax.tree_util.tree_map(lambda a, b: a - lr_t * b, p, g)
-                return p, None
+            def body(t, p):
+                bi = idx[t]
+                g = grad(p, xs[bi], ys[bi], valid[t], counts[t])
+                return jax.tree_util.tree_map(
+                    lambda a, b: a - lr_steps[t] * b, p, g)
 
-            p, _ = jax.lax.scan(body, anchor, (idx, valid, counts, lr_steps))
-            return spec.flatten(p)
+            # n_steps is traced and every vmap leaves it unbatched, so the
+            # loop stays one while loop with a scalar predicate, and each
+            # trip count runs on the same compiled program.
+            return spec.flatten(jax.lax.fori_loop(0, n_steps, body, anchor))
+
+        return member
+
+    @staticmethod
+    def _build(cfg, spec, prox, align, fam, member_kernel="vmap"):
+        local_sgd = CohortEngine._local_sgd(cfg, spec, prox, align, fam,
+                                            member_kernel)
+
+        def member(x_all, y_all, p0_flat, cid, idx, valid, counts, lr_steps,
+                   n_steps):
+            # x_all[cid]: this member's (n_max, ...) rows of the slab
+            return local_sgd(x_all[cid], y_all[cid], p0_flat, idx, valid,
+                             counts, lr_steps, n_steps)
+
+        over_members = jax.vmap(
+            member, in_axes=(None, None, 0, 0, 0, 0, 0, 0, None))
 
         @jax.jit
         def run(x_all, y_all, params_stack, cids, idx, valid, counts,
-                lr_steps):
-            w = jax.vmap(member, in_axes=(None, None, 0, 0, 0, 0, 0, 0))(
-                x_all, y_all, params_stack, cids, idx, valid, counts,
-                lr_steps)
+                lr_steps, n_steps):
+            w = over_members(x_all, y_all, params_stack, cids, idx, valid,
+                             counts, lr_steps, n_steps)
             return w - params_stack, w
 
         # The sweep engine's variant: one more vmap over a leading lane
         # axis. Lanes share the data slab, the member (client) assignment,
         # the validity masks/counts (schedule shapes depend only on client
-        # sizes) and the lr schedule — all lane-invariant because the event
-        # timeline is shared; the dispatch snapshots and the batch-index
-        # permutations are per-lane (per-lane weights / shuffle seeds).
-        over_members = jax.vmap(member, in_axes=(None, None, 0, 0, 0, 0, 0, 0))
-
+        # sizes), the lr schedule and the trip count — all lane-invariant
+        # because the event timeline is shared; the dispatch snapshots and
+        # the batch-index permutations are per-lane (per-lane weights /
+        # shuffle seeds).
         @jax.jit
         def run_lanes(x_all, y_all, params_stack, cids, idx, valid, counts,
-                      lr_steps):
+                      lr_steps, n_steps):
             w = jax.vmap(over_members,
-                         in_axes=(None, None, 0, None, 0, None, None, None))(
+                         in_axes=(None, None, 0, None, 0, None, None, None,
+                                  None))(
                 x_all, y_all, params_stack, cids, idx, valid, counts,
-                lr_steps)
+                lr_steps, n_steps)
             return w - params_stack, w
 
         return run, run_lanes
@@ -221,7 +241,12 @@ class CohortEngine:
         (num_steps, bs_pad) frame. Same RandomState stream as the legacy
         ``ClientDataset.epochs`` iterator. Returns (idx, valid f32 masks,
         counts = per-step valid totals clamped to >= 1, nvalid per-step raw
-        totals for lr gating)."""
+        totals for lr gating).
+
+        The frame stays at ``num_steps`` whatever the wave, so each row
+        bucket compiles one program; the loop itself stops at the wave's
+        longest member (``_trip_count``), and the frame's tail past it is
+        never read."""
         B = len(cids)
         idx = np.zeros((B, self.num_steps, self.bs_pad), np.int32)
         valid = np.zeros((B, self.num_steps, self.bs_pad), np.float32)
@@ -236,6 +261,14 @@ class CohortEngine:
         counts = np.maximum(nvalid, 1.0)
         return idx, valid, counts, nvalid
 
+    def _trip_count(self, cids: np.ndarray, nvalid: np.ndarray) -> np.int32:
+        """The wave's local-SGD trip count: its longest real member's steps
+        (bucket padding rows are added after, and do not count)."""
+        n_steps = int(self.steps_per_client[cids].max())
+        assert n_steps == int((nvalid > 0.0).sum(axis=1).max()), \
+            "trip count disagrees with the built schedules"
+        return np.int32(n_steps)
+
     def cohort_update(self, params_stack: jnp.ndarray, cids: Sequence[int],
                       lrs: Sequence[float], seeds: Sequence[int]
                       ) -> Tuple[jnp.ndarray, jnp.ndarray]:
@@ -248,8 +281,9 @@ class CohortEngine:
         The host's part (schedules, padding, uploads, the compiled call's
         enqueue) is the ``cohort.enqueue`` span; each call records one
         ``cohort.wave``: ``members``, bucketed ``rows``, the members' real
-        local ``steps``, the ``schedule`` every row runs, and the real
-        ``samples`` those steps train on.
+        local ``steps``, the ``schedule`` every row runs (the trip count of
+        this wave's loop: its longest member's steps, not the engine-wide
+        ``num_steps``), and the real ``samples`` those steps train on.
         """
         with obs.span("cohort.enqueue"):
             B = int(params_stack.shape[0])
@@ -261,10 +295,11 @@ class CohortEngine:
             # steps, 0 on padded steps (making them exact no-ops)
             lr_steps = (np.asarray(lrs, np.float64)[:, None]
                         * (nvalid > 0.0)).astype(np.float32)
+            n_steps = self._trip_count(cids, nvalid)
             Bp = bucket_size(B, self._data_kind)
             steps = self.steps_per_client[cids]
             obs.record("cohort.wave", members=B, rows=Bp,
-                       steps=int(steps.sum()), schedule=self.num_steps,
+                       steps=int(steps.sum()), schedule=int(n_steps),
                        samples=int((steps * np.minimum(
                            self.batch_size, self.sizes[cids])).sum()))
             pad = Bp - B
@@ -281,10 +316,11 @@ class CohortEngine:
                     [counts, np.ones((pad,) + counts.shape[1:],
                                      counts.dtype)])
             deltas, w = self._launch(params_stack, cids, pad, idx, valid,
-                                     counts, lr_steps)
+                                     counts, lr_steps, n_steps)
             return deltas[:B], w[:B]
 
-    def _launch(self, params_stack, cids, pad, idx, valid, counts, lr_steps):
+    def _launch(self, params_stack, cids, pad, idx, valid, counts, lr_steps,
+                n_steps):
         """The compiled wave over the resident slab, which each member
         indexes by its client id (padded rows by client 0)."""
         cids = np.concatenate([cids, np.zeros((pad,), cids.dtype)])
@@ -298,7 +334,9 @@ class CohortEngine:
                 jax.device_put(a, NamedSharding(
                     self.mesh, P(*([ax] + [None] * (a.ndim - 1)))))
                 for a in args)
-        return self._run(self.x, self.y, *args)
+            # the trip count is a 0-d scalar every device reads whole
+            n_steps = jax.device_put(n_steps, NamedSharding(self.mesh, P()))
+        return self._run(self.x, self.y, *args, n_steps)
 
     def sweep_update(self, params_stack: jnp.ndarray, cids: Sequence[int],
                      lrs: Sequence[float], seeds_per_lane: np.ndarray
@@ -332,6 +370,7 @@ class CohortEngine:
             idx[s], valid, counts, nvalid = built[key]
         lr_steps = (np.asarray(lrs, np.float64)[:, None]
                     * (nvalid > 0.0)).astype(np.float32)
+        n_steps = self._trip_count(cids, nvalid)
         Bp = bucket_size(B, self._data_kind)
         if Bp > B:
             pad = Bp - B
@@ -353,7 +392,7 @@ class CohortEngine:
         deltas, w = self._run_lanes(
             self.x, self.y, params_stack, jnp.asarray(cids),
             jnp.asarray(idx), jnp.asarray(valid), jnp.asarray(counts),
-            jnp.asarray(lr_steps))
+            jnp.asarray(lr_steps), n_steps)
         return deltas[:, :B], w[:, :B]
 
 
@@ -408,50 +447,29 @@ class StreamingCohortEngine(CohortEngine):
 
     @staticmethod
     def _build_rows(cfg, spec, prox, align, fam, member_kernel="vmap"):
-        def member(xs, ys, p0_flat, idx, valid, counts, lr_steps):
-          with member_math.routing(member_kernel):
-            # identical member program to CohortEngine._build, minus the
-            # in-jit x_all[cid] gather: xs/ys are this member's rows
-            anchor = spec.unflatten(p0_flat)
-
-            def loss(p, xb, yb, vm, cnt):
-                base = fam.client_loss(p, fam.masked_batch(xb, yb, vm, cnt),
-                                       cfg, SINGLE_DEVICE_RULES)
-                if prox > 0.0:
-                    base = base + 0.5 * prox * tu.tree_sq_norm(
-                        tu.tree_sub(p, anchor))
-                if align > 0.0:
-                    base = base + 0.5 * align * tu.tree_sq_norm(
-                        tu.tree_sub(_head(p), _head(anchor)))
-                return base
-
-            grad = jax.grad(loss)
-
-            def body(p, sl):
-                bi, vm, cnt, lr_t = sl
-                g = grad(p, xs[bi], ys[bi], vm, cnt)
-                p = jax.tree_util.tree_map(lambda a, b: a - lr_t * b, p, g)
-                return p, None
-
-            p, _ = jax.lax.scan(body, anchor, (idx, valid, counts, lr_steps))
-            return spec.flatten(p)
+        # the same member program as CohortEngine._build, minus the in-jit
+        # x_all[cid] gather: each member receives its own rows
+        over_members = jax.vmap(
+            CohortEngine._local_sgd(cfg, spec, prox, align, fam,
+                                    member_kernel),
+            in_axes=(0, 0, 0, 0, 0, 0, 0, None))
 
         @jax.jit
-        def run(x_rows, y_rows, params_stack, idx, valid, counts, lr_steps):
-            w = jax.vmap(member, in_axes=(0, 0, 0, 0, 0, 0, 0))(
-                x_rows, y_rows, params_stack, idx, valid, counts, lr_steps)
+        def run(x_rows, y_rows, params_stack, idx, valid, counts, lr_steps,
+                n_steps):
+            w = over_members(x_rows, y_rows, params_stack, idx, valid,
+                             counts, lr_steps, n_steps)
             return w - params_stack, w
-
-        over_members = jax.vmap(member, in_axes=(0, 0, 0, 0, 0, 0, 0))
 
         @jax.jit
         def run_lanes(x_rows, y_rows, params_stack, idx, valid, counts,
-                      lr_steps):
-            # lanes share the wave's row slab, schedules shapes and lr; the
-            # snapshots and index permutations are per-lane
+                      lr_steps, n_steps):
+            # lanes share the wave's row slab, schedules shapes, lr and trip
+            # count; the snapshots and index permutations are per-lane
             w = jax.vmap(over_members,
-                         in_axes=(None, None, 0, 0, None, None, None))(
-                x_rows, y_rows, params_stack, idx, valid, counts, lr_steps)
+                         in_axes=(None, None, 0, 0, None, None, None, None))(
+                x_rows, y_rows, params_stack, idx, valid, counts, lr_steps,
+                n_steps)
             return w - params_stack, w
 
         return run, run_lanes
@@ -467,12 +485,13 @@ class StreamingCohortEngine(CohortEngine):
                 [y, jnp.zeros((pad,) + y.shape[1:], y.dtype)])
         return x, y
 
-    def _launch(self, params_stack, cids, pad, idx, valid, counts, lr_steps):
+    def _launch(self, params_stack, cids, pad, idx, valid, counts, lr_steps,
+                n_steps):
         """The compiled wave over the members' own streamed rows."""
         x, y = self._wave_rows(cids, pad)
         return self._run_rows(x, y, params_stack, jnp.asarray(idx),
                               jnp.asarray(valid), jnp.asarray(counts),
-                              jnp.asarray(lr_steps))
+                              jnp.asarray(lr_steps), n_steps)
 
     def sweep_update(self, params_stack: jnp.ndarray, cids: Sequence[int],
                      lrs: Sequence[float], seeds_per_lane: np.ndarray
@@ -491,6 +510,7 @@ class StreamingCohortEngine(CohortEngine):
             idx[s], valid, counts, nvalid = built[key]
         lr_steps = (np.asarray(lrs, np.float64)[:, None]
                     * (nvalid > 0.0)).astype(np.float32)
+        n_steps = self._trip_count(cids, nvalid)
         Bp = bucket_size(B, self._data_kind)
         pad = Bp - B
         x, y = self._wave_rows(cids, pad)
@@ -510,5 +530,5 @@ class StreamingCohortEngine(CohortEngine):
                 [counts, np.ones((pad,) + counts.shape[1:], counts.dtype)])
         deltas, w = self._run_rows_lanes(
             x, y, params_stack, jnp.asarray(idx), jnp.asarray(valid),
-            jnp.asarray(counts), jnp.asarray(lr_steps))
+            jnp.asarray(counts), jnp.asarray(lr_steps), n_steps)
         return deltas[:, :B], w[:, :B]

@@ -13,7 +13,7 @@ Two client engines drive the same event semantics:
     training depends only on its dispatch snapshot, so all events due before
     the earliest possible completion of any re-dispatch (``t_first +
     latency_lo``) form a *wave* that trains as ONE compiled call
-    (``federated.cohort.CohortEngine`` — vmap over clients, scan over local
+    (``federated.cohort.CohortEngine`` — vmap over clients, a loop over local
     steps, flat parameter layout end to end: dispatch snapshots are the
     server's flat (d,) vector, never a pytree). Receives then apply strictly
     in completion order, so the receive order, per-dispatch lr/seed

@@ -8,6 +8,7 @@ event loop's receive order, RNG streams, and per-dispatch lr/seed
 assignment. CPU-only, QUICK-world sized.
 """
 import dataclasses
+import time
 
 import jax
 import jax.numpy as jnp
@@ -99,6 +100,91 @@ def test_cohort_padding_rows_are_noops(world):
     d4, _ = eng.cohort_update(jnp.stack([flat] * 4), [0, 1, 2, 3],
                               [0.01] * 4, [5, 6, 7, 8])
     np.testing.assert_array_equal(np.asarray(d3), np.asarray(d4[:3]))
+
+
+# -- the wave's trip count ---------------------------------------------------
+
+@pytest.fixture(scope="module")
+def ragged(world):
+    """A Dirichlet-0.1 split (2 epochs at batch 64) and a wave of its three
+    shortest clients, whose longest member stops short of ``num_steps``."""
+    cfg, train, _, params = world
+    parts = dirichlet_partition(train, 8, alpha=0.1, seed=0)
+    datasets = [ClientDataset(train.subset(ix)) for ix in parts]
+    spec, eng = _engine_for(cfg, params, datasets, local_epochs=2,
+                            batch_size=64)
+    short = [int(c) for c in np.argsort(eng.steps_per_client,
+                                        kind="stable")[:3]]
+    assert eng.steps_per_client[short].max() < eng.num_steps
+    flat = jnp.array(spec.flatten(params), copy=True)
+    return cfg, params, datasets, spec, eng, short, jnp.stack([flat] * 3)
+
+
+def test_short_wave_stops_at_its_longest_member(ragged, monkeypatch):
+    """The jitted ``run`` on one short wave's operands: the wave's own trip
+    count and the engine-wide ``num_steps`` give the same deltas to the
+    bit, so the steps the cut leaves out were exact no-ops."""
+    _, _, _, _, eng, short, stack = ragged
+    calls, run = [], eng._run
+
+    def spy(*args):
+        calls.append(args)
+        return run(*args)
+
+    monkeypatch.setattr(eng, "_run", spy)
+    d, w = eng.cohort_update(stack, short, [0.01, 0.008, 0.012], [5, 6, 7])
+    (args,) = calls
+    assert int(args[-1]) == eng.steps_per_client[short].max()
+    d_full, w_full = run(*args[:-1], np.int32(eng.num_steps))
+    np.testing.assert_array_equal(np.asarray(d), np.asarray(d_full[:3]))
+    np.testing.assert_array_equal(np.asarray(w), np.asarray(w_full[:3]))
+
+
+def test_one_program_serves_every_trip_count(ragged, monkeypatch):
+    """Waves of one row bucket with different trip counts run on one
+    compiled program: the trip count is traced, never static."""
+    from repro.common import obs
+    from repro.models import registry
+    cfg, _, _, _, eng, short, stack = ragged
+    run, _ = CohortEngine._build(cfg, eng.spec, eng.prox, eng.align,
+                                 registry.get_family(cfg), eng.member_kernel)
+    monkeypatch.setattr(eng, "_run", run)
+    longest = int(np.argmax(eng.steps_per_client))
+    t0 = time.perf_counter()
+    for cids in ([short[0]], short[1:], short, [short[0], longest]):
+        eng.cohort_update(stack[:len(cids)], cids, [0.01] * len(cids),
+                          list(range(len(cids))))
+        assert run._cache_size() == 1
+    waves = obs.records("cohort.wave", t0, time.perf_counter())
+    assert {w["rows"] for w in waves} == {4}
+    assert len({w["schedule"] for w in waves}) >= 3
+
+
+def test_sweep_and_streaming_match_cohort_on_a_short_wave(ragged):
+    """The lane program and the streaming engine's two programs cut the
+    same short wave at the same trip count: their deltas equal
+    ``cohort_update``'s."""
+    from repro.data import ClientSlabStore
+    from repro.federated.cohort import StreamingCohortEngine
+    cfg, params, datasets, spec, eng, short, stack = ragged
+    lrs, seeds = [0.01, 0.008, 0.012], [5, 6, 7]
+    d, _ = eng.cohort_update(stack, short, lrs, seeds)
+    store = ClientSlabStore.build(datasets, shard_size=3, cache_shards=2,
+                                  promote=1)
+    streaming = StreamingCohortEngine(cfg, store, spec, params,
+                                      local_epochs=2, batch_size=64)
+    lanes = np.asarray([seeds, seeds])
+    got = {
+        "sweep": eng.sweep_update(jnp.stack([stack] * 2), short, lrs,
+                                  lanes)[0],
+        "streaming": streaming.cohort_update(stack, short, lrs, seeds)[0],
+        "streaming sweep": streaming.sweep_update(
+            jnp.stack([stack] * 2), short, lrs, lanes)[0],
+    }
+    for name, deltas in got.items():
+        deltas = np.asarray(deltas).reshape((-1,) + d.shape)
+        for lane in deltas:
+            np.testing.assert_array_equal(lane, np.asarray(d), err_msg=name)
 
 
 QUICK = dict(num_clients=16, horizon=10_000, eval_every=5_000, seed=0)

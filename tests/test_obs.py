@@ -68,17 +68,15 @@ def test_compiles_are_counted():
     assert after["records"] > before and after["seconds"] > 0
 
 
-def test_cohort_wave_counts_match_the_probe():
-    """The engine's ``cohort.wave`` record and the benchmark probe's own
-    count of the same wave, on the probe test's small engine: clients of
-    20, 70 and 140 samples, one epoch at batch 32 (1, 2 and 4 steps); a
-    wave of clients 0 and 2 in a 4-row bucket of the 4-step schedule."""
+def _tiny_engine():
+    """The probe test's small engine: clients of 20, 70 and 140 samples,
+    one epoch at batch 32 (1, 2 and 4 steps). Returns (engine, spec,
+    params)."""
     from repro.common import tree as tu
     from repro.data.loader import ClientDataset, StackedClients
     from repro.data.synthetic import SyntheticClassification
     from repro.federated.cohort import CohortEngine
     from bench import reference, run
-    from bench.probe import Probe
     from bench.test_bench import TINY
 
     cfg = run.program_config(dict(TINY), exact=False)
@@ -90,6 +88,17 @@ def test_cohort_wave_counts_match_the_probe():
     spec = tu.FlatSpec(params)
     engine = CohortEngine(cfg, StackedClients.from_datasets(clients), spec,
                           params, local_epochs=1, batch_size=32)
+    return engine, spec, params
+
+
+def test_cohort_wave_counts_match_the_probe():
+    """The engine's ``cohort.wave`` record and the benchmark probe's own
+    count of the same wave, on the probe test's small engine: clients of
+    20, 70 and 140 samples, one epoch at batch 32 (1, 2 and 4 steps); a
+    wave of clients 0 and 2 in a 4-row bucket of the 4-step schedule."""
+    from bench.probe import Probe
+
+    engine, spec, params = _tiny_engine()
     probe = Probe({"prefix_updates": 1, "rate_hint": 1.0}, 1.0, spans=True)
     probe.state = "window"
     w = jax.numpy.stack([spec.flatten(params)] * 2)
@@ -104,6 +113,23 @@ def test_cohort_wave_counts_match_the_probe():
             wave["rows"] * wave["schedule"], wave["samples"]) == (
         mine["members"], mine["rows"], mine["useful_steps"],
         mine["executed_steps"], mine["samples"])
+
+
+@pytest.mark.parametrize("cids, schedule", [([0], 1), ([1, 0], 2),
+                                             ([1, 0, 2], 4)])
+def test_cohort_wave_schedule_is_the_trip_count(cids, schedule):
+    """``cohort.wave``'s ``schedule`` is the trip count the wave ran: its
+    longest member's steps on ``_tiny_engine`` (clients of 1, 2 and 4
+    steps), and the engine-wide ``num_steps`` where the wave holds the
+    longest client."""
+    engine, spec, params = _tiny_engine()
+    w = jax.numpy.stack([spec.flatten(params)] * len(cids))
+    t0 = time.perf_counter()
+    engine.cohort_update(w, cids, [0.01] * len(cids), list(range(len(cids))))
+    (wave,) = obs.records("cohort.wave", t0, time.perf_counter())
+    assert wave["schedule"] == schedule == max(
+        engine.steps_per_client[c] for c in cids)
+    assert (wave["schedule"] == engine.num_steps) == (2 in cids)
 
 
 def _repro_events(trace_dir):
