@@ -6,7 +6,7 @@ benchmark's runs never enter one). A cell on one chip can have these:
 ``state_unchanged``  the cohort step returns every member's snapshot: the
                      client updates are zero;
 ``half_batch``       each local step takes its loss over the first half of
-                     its batch (the mean over the rest): the image family's
+                     its batch (the mean over the rest): the program family's
                      batch mask drops the second half;
 ``answer_altered``   the first member of every wave has its update negated
                      where the cohort step produces it.
@@ -47,10 +47,10 @@ def _negate_first(params_stack, deltas, w):
 
 
 @contextlib.contextmanager
-def _half_batch():
+def _half_batch(family: str):
     import jax.numpy as jnp
     from repro.models import registry
-    fam = registry.get_family("cnn")
+    fam = registry.get_family(family)
 
     def masked_batch(xb, yb, vm, cnt):
         half = vm.shape[0] // 2
@@ -65,11 +65,13 @@ def _half_batch():
         registry.register_family(fam, override=True)
 
 
-def plant(name: str):
+def plant(name: str, family: str):
+    """The fault ``name`` in a run of the program's model family
+    ``family`` (``ModelConfig.family``)."""
     if name == "state_unchanged":
         return _engine_output(_unchanged)
     if name == "answer_altered":
         return _engine_output(_negate_first)
     if name == "half_batch":
-        return _half_batch()
+        return _half_batch(family)
     raise KeyError(f"unknown fault {name!r}; known: {FAULTS}")

@@ -33,7 +33,9 @@ phase of the deadline.
 on (the traced run), ``Dispatcher.dispatch_many``, ``cohort_update``,
 ``receive_many``, the batched sketch and the evaluation are timed on the
 host clock and named in the profiler's trace (``TraceAnnotation``), and
-every wave's member-steps are counted.
+every wave's member-steps are counted: its members' real steps, and the
+steps its bucketed rows executed, rows times the trip count the program
+recorded for the call (``cohort.wave``'s ``schedule``).
 
 Compile events come from JAX's own monitoring: every program built in the
 process fires ``/jax/core/compile/backend_compile_duration``, a load from
@@ -234,15 +236,19 @@ class Probe:
             return real(ps, cids, lrs, seeds)
         if not self.spans_on:
             return real(ps, cids, lrs, seeds)
+        from repro.common import obs
         out = self._span("cohort_update", real, ps, cids, lrs, seeds)
+        _, t0, t1 = self.spans[-1]
+        # the trip count every row ran: the program's record of this call
+        (wave,) = obs.records("cohort.wave", t0, t1)
         cids = np.asarray(cids, np.int64)
         steps = engine.steps_per_client[cids]
         bs = np.minimum(engine.batch_size, engine.sizes[cids])
         rows = int(bucket_size(b, engine._data_kind))
         self.waves.append({
-            "t": self.spans[-1][1], "members": b, "rows": rows,
+            "t": t0, "members": b, "rows": rows,
             "useful_steps": int(steps.sum()),
-            "executed_steps": rows * int(engine.num_steps),
+            "executed_steps": rows * int(wave["schedule"]),
             "samples": int((steps * bs).sum())})
         return out
 

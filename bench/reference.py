@@ -1,9 +1,11 @@
-"""The plain reference: the paper's CNNs, a client's local SGD, the FedPSA
-sensitivity sketch and the FedPSA server, in straightforward ``jax.numpy``.
+"""The plain reference: a client's local SGD, the FedPSA sensitivity sketch
+and the FedPSA server, in straightforward ``jax.numpy``, over the model and
+the trained leaves of the configuration's family module (``bench.families``:
+its ``loss``, ``leaf_names`` and ``init_weights``).
 
 It imports nothing of the program and takes nothing the program made: the
 weights come from ``init_weights`` (the benchmark's own, from the seed), the
-data from ``bench.traffic``, and from a run of the program only the order
+data from the family's world, and from a run of the program only the order
 in which client updates arrived (client id and the model version each was
 dispatched with), which is the traffic the run served. Its arithmetic is
 the paper's (FedPSA §4-5, Algorithm 1):
@@ -27,13 +29,13 @@ the precision a later change would be tempted to drop to.
 """
 from __future__ import annotations
 
-from typing import Dict, List, Sequence, Tuple
+from typing import List, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from bench import traffic as tr
+from bench import families, traffic as tr
 
 SKETCH_K = 16
 SKETCH_SEED = 42
@@ -46,101 +48,23 @@ SERVER_LR = 1.0
 M32 = 0xFFFFFFFF
 
 
-def leaf_names(config: dict) -> List[Tuple[str, str]]:
-    """(layer, w|b) in the order the flat parameter vector lays them out
-    (sorted layer names, bias before weight)."""
-    layers = [f"conv{i}" for i in range(len(config["cnn_channels"]))]
-    layers += [f"fc{i}" for i in range(len(config["mlp_hidden"]) + 1)]
-    return [(l, p) for l in sorted(layers) for p in ("b", "w")]
-
-
-def leaf_shapes(config: dict) -> Dict[Tuple[str, str], tuple]:
-    H, W, C = config["input_hw"]
-    k = config["cnn_kernel"]
-    shapes = {}
-    cin = C
-    for i, ch in enumerate(config["cnn_channels"]):
-        shapes[(f"conv{i}", "w")] = (k, k, cin, ch)
-        shapes[(f"conv{i}", "b")] = (ch,)
-        cin = ch
-        H, W = H // 2, W // 2
-    dims = [H * W * cin] + list(config["mlp_hidden"]) + [config["num_classes"]]
-    for i in range(len(dims) - 1):
-        shapes[(f"fc{i}", "w")] = (dims[i], dims[i + 1])
-        shapes[(f"fc{i}", "b")] = (dims[i + 1],)
-    return shapes
-
-
 def leaf_sizes(config: dict) -> List[int]:
-    shapes = leaf_shapes(config)
-    return [int(np.prod(shapes[n])) for n in leaf_names(config)]
+    """The sizes of the trained leaves, in the flat vector's order."""
+    fam = families.load(config)
+    shapes = fam.leaf_shapes(config)
+    return [int(np.prod(shapes[n])) for n in fam.leaf_names(config)]
 
 
 def init_weights(config: dict, seed: int) -> dict:
-    """The run's initial weights, made on the device in one jitted call:
-    truncated-normal fan-in weights (convs at 1/sqrt(k*k*c_in)) and zero
-    biases, in float32, the type the configuration trains in."""
-    shapes = leaf_shapes(config)
-    names = [n for n in leaf_names(config) if n[1] == "w"]
-
-    @jax.jit
-    def make(key):
-        keys = jax.random.split(key, len(names))
-        out = {}
-        for kk, (layer, _) in zip(keys, names):
-            shp = shapes[(layer, "w")]
-            fan_in = (int(np.prod(shp[:3])) if layer.startswith("conv")
-                      else shp[0])
-            w = jax.random.truncated_normal(kk, -2.0, 2.0, shp, jnp.float32)
-            out[layer] = {"w": w / np.sqrt(fan_in),
-                          "b": jnp.zeros(shapes[(layer, "b")], jnp.float32)}
-        return out
-
-    return make(jax.random.PRNGKey(tr.sub_seeds(seed)["model"]))
+    """The run's initial weights (``tests/test_obs.py`` builds its engine
+    from them)."""
+    return families.load(config).init_weights(config, seed)
 
 
-# ---------------------------------------------------------------------------
-# The model
-# ---------------------------------------------------------------------------
-
-def _precision(dtype):
-    return (jax.lax.Precision.HIGHEST if dtype == jnp.float32
-            else jax.lax.Precision.DEFAULT)
-
-
-def forward(params, x, config: dict, dtype=jnp.float32):
-    """(B, H, W, C) images -> (B, classes) logits: 5x5 SAME convs with
-    bias and ReLU, each followed by a 2x2 max-pool, then the fc stack with
-    ReLU between layers."""
-    prec = _precision(dtype)
-    x = x.astype(dtype)
-    for i in range(len(config["cnn_channels"])):
-        p = params[f"conv{i}"]
-        x = jax.lax.conv_general_dilated(
-            x, p["w"].astype(dtype), (1, 1), "SAME",
-            dimension_numbers=("NHWC", "HWIO", "NHWC"), precision=prec)
-        x = jax.nn.relu(x + p["b"].astype(dtype))
-        x = jax.lax.reduce_window(x, -jnp.inf, jax.lax.max,
-                                  (1, 2, 2, 1), (1, 2, 2, 1), "VALID")
-    x = x.reshape(x.shape[0], -1)
-    n_fc = len(config["mlp_hidden"]) + 1
-    for i in range(n_fc):
-        p = params[f"fc{i}"]
-        x = jnp.dot(x, p["w"].astype(dtype), precision=prec) \
-            + p["b"].astype(dtype)
-        if i < n_fc - 1:
-            x = jax.nn.relu(x)
-    return x
-
-
-def xent(params, x, y, weight, config: dict, dtype=jnp.float32):
-    """Cross-entropy summed over the rows with ``weight`` 1, over their
-    count: the mean over the real samples of a padded batch."""
-    logits = forward(params, x, config, dtype)
-    lse = jax.nn.logsumexp(logits, axis=-1)
-    gold = jnp.take_along_axis(logits, y[:, None], axis=-1)[:, 0]
-    w = weight.astype(dtype)
-    return jnp.sum((lse - gold) * w) / jnp.maximum(jnp.sum(w), 1)
+def _get(tree, path):
+    for key in path:
+        tree = tree[key]
+    return tree
 
 
 # ---------------------------------------------------------------------------
@@ -153,10 +77,12 @@ class Client:
     def __init__(self, config: dict, traffic: dict, dtype=jnp.float32):
         self.config, self.traffic, self.dtype = config, traffic, dtype
         self.pad = int(traffic["batch"])
+        fam = families.load(config)
+        self.keys = fam.BATCH_KEYS
 
         @jax.jit
-        def step(p, xb, yb, wb, lr):
-            g = jax.grad(xent)(p, xb, yb, wb, config, dtype)
+        def step(p, batch, wb, lr):
+            g = jax.grad(fam.loss)(p, batch, wb, config, dtype)
             return jax.tree_util.tree_map(lambda a, b: a - lr.astype(a.dtype)
                                           * b, p, g)
 
@@ -175,7 +101,8 @@ class Client:
         lr = jnp.asarray(lr, self.dtype)
         for idx in sched:
             rows[:bs] = idx
-            p = self._step(p, x[rows], y[rows].astype(np.int32), weight, lr)
+            batch = dict(zip(self.keys, (x[rows], y[rows].astype(np.int32))))
+            p = self._step(p, batch, weight, lr)
         return p
 
 
@@ -215,30 +142,31 @@ def _project(vec, seed_u32: int, k: int):
 def make_sketch(config: dict, calib: dict, dtype=jnp.float32):
     """params pytree -> (k,) f32 sensitivity sketch on the calibration
     batch, one compiled program."""
-    names = leaf_names(config)
-    n = calib["x"].shape[0]
-    ones = jnp.ones((n,), jnp.float32)
-    cx = jnp.asarray(calib["x"])
-    cy = jnp.asarray(calib["y"], jnp.int32)
+    fam = families.load(config)
+    names = fam.leaf_names(config)
+    cal = {k: jnp.asarray(v) for k, v in calib.items()}
+    n = next(iter(cal.values())).shape[0]
 
-    def loss(p, x, y):
-        return xent(p, x, y, jnp.ones((x.shape[0],), jnp.float32), config,
-                    dtype)
+    def loss(p, batch):
+        rows = next(iter(batch.values())).shape[0]
+        return fam.loss(p, batch, jnp.ones((rows,), jnp.float32), config,
+                        dtype)
 
     @jax.jit
     def sketch(params):
         p = jax.tree_util.tree_map(lambda a: a.astype(dtype), params)
-        g = jax.grad(lambda q: xent(q, cx, cy, ones, config, dtype))(p)
+        g = jax.grad(loss)(p, cal)
         m = n // FISHER_MICRO
         fisher = jax.tree_util.tree_map(jnp.zeros_like, p)
         for j in range(FISHER_MICRO):
-            gj = jax.grad(loss)(p, cx[j * m:(j + 1) * m], cy[j * m:(j + 1) * m])
+            gj = jax.grad(loss)(p, {k: v[j * m:(j + 1) * m]
+                                    for k, v in cal.items()})
             fisher = jax.tree_util.tree_map(lambda a, b: a + b * b, fisher, gj)
         total = jnp.zeros((SKETCH_K,), jnp.float32)
-        for i, (layer, kind) in enumerate(names):
-            th = p[layer][kind].reshape(-1)
-            gi = g[layer][kind].reshape(-1)
-            fi = fisher[layer][kind].reshape(-1) / FISHER_MICRO
+        for i, path in enumerate(names):
+            th = _get(p, path).reshape(-1)
+            gi = _get(g, path).reshape(-1)
+            fi = _get(fisher, path).reshape(-1) / FISHER_MICRO
             s = jnp.abs(gi * th - 0.5 * fi * th * th)
             total = total + _project(s, leaf_seed(SKETCH_SEED, i),
                                      SKETCH_K).astype(jnp.float32)
@@ -259,18 +187,22 @@ def cosine(a: np.ndarray, b: np.ndarray) -> float:
 # ---------------------------------------------------------------------------
 
 def flatten(config: dict, params) -> np.ndarray:
-    return np.concatenate([np.asarray(params[l][k], np.float32).reshape(-1)
-                           for l, k in leaf_names(config)])
+    names = families.load(config).leaf_names(config)
+    return np.concatenate([np.asarray(_get(params, path), np.float32)
+                           .reshape(-1) for path in names])
 
 
 def unflatten(config: dict, vec: np.ndarray) -> dict:
-    shapes = leaf_shapes(config)
+    fam = families.load(config)
+    shapes = fam.leaf_shapes(config)
     out: dict = {}
     off = 0
-    for layer, kind in leaf_names(config):
-        n = int(np.prod(shapes[(layer, kind)]))
-        out.setdefault(layer, {})[kind] = jnp.asarray(
-            vec[off:off + n].reshape(shapes[(layer, kind)]))
+    for path in fam.leaf_names(config):
+        n = int(np.prod(shapes[path]))
+        node = out
+        for key in path[:-1]:
+            node = node.setdefault(key, {})
+        node[path[-1]] = jnp.asarray(vec[off:off + n].reshape(shapes[path]))
         off += n
     return out
 

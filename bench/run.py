@@ -11,8 +11,9 @@ configuration (``bench/configs/<config>.json``) and its traffic mix
    (``repro.launch.compile_cache``); the device must be a TPU with as many
    chips as the cell asks for, else the run exits non-zero and prints no
    result;
-2. the world is made from the seed (``bench.traffic``), the weights on the
-   device in one jitted call (``bench.reference.init_weights``);
+2. the configuration's family module (``bench.families``) makes the world
+   from the seed and the mix, the weights on the device in one jitted
+   call, and the program's client datasets over the world;
 3. one FedPSA experiment runs through the program's normal entry,
    ``repro.federated.run_algorithm`` (``run_async``, the cohort engine, the
    scanned ingest and the compiled Pallas kernels), at the paper's
@@ -57,8 +58,6 @@ for _p in (os.path.join(CHECKOUT, "src"), CHECKOUT):
 
 ALGORITHM = "fedpsa"
 TRACE_SECONDS = 10.0
-CONFIG_FIELDS = ("family", "input_hw", "cnn_channels", "cnn_kernel",
-                 "mlp_hidden", "num_classes", "param_dtype")
 
 
 class NoChip(RuntimeError):
@@ -107,16 +106,19 @@ def cell_metrics(bench: dict, cell: str, kind: str) -> list:
 
 
 def program_config(config: dict, exact: bool = True):
-    """The program's configuration, checked against the benchmark's file;
-    with ``exact`` off (the tests' small models), derived from it."""
+    """The program's configuration, checked against the benchmark's file
+    on the keys its family module names (``FIELDS``); with ``exact`` off
+    (the tests' small models), derived from it."""
     import dataclasses
+    from bench import families
     from repro.configs import get_config
     cfg = get_config(config["program_config"])
+    fields = families.load(config).FIELDS
     if not exact:
         return dataclasses.replace(cfg, **{
             k: tuple(config[k]) if isinstance(config[k], list) else config[k]
-            for k in CONFIG_FIELDS})
-    for key in CONFIG_FIELDS:
+            for k in fields})
+    for key in fields:
         have = getattr(cfg, key)
         want = config[key]
         if (list(have) if isinstance(have, tuple) else have) != want:
@@ -141,23 +143,13 @@ def sim_config(traffic: dict, shuffle_seed: int):
         timeline_seed=int(traffic["timeline_seed"]))
 
 
-def program_inputs(world):
-    from repro.data.loader import ClientDataset
-    from repro.data.synthetic import SyntheticClassification
-    K = world.num_classes
-    clients = [ClientDataset(SyntheticClassification(*world.client(c), K))
-               for c in range(len(world.sizes))]
-    test = SyntheticClassification(world.x_test, world.y_test, K)
-    return clients, test
-
-
 @dataclass
 class Context:
     """What a per-layer metric reader (``bench/metrics/<name>.py``) reads."""
     config: dict
     traffic: dict
     peaks: dict
-    params: int
+    params: int             # the trained parameters the server holds
     t_start: float
     t_end: float
     window_s: float
@@ -185,7 +177,7 @@ def run_cell(cell: dict, seed: int, seconds: float, trace: bool, *,
     import jax
     from repro.launch.compile_cache import enable_compile_cache
 
-    from bench import correct, faults, flops, peaks as peak_table
+    from bench import correct, faults, families, flops, peaks as peak_table
     from bench import probe as probe_lib, reference, traffic as tr
 
     if require_tpu:
@@ -206,10 +198,11 @@ def run_cell(cell: dict, seed: int, seconds: float, trace: bool, *,
     from repro.core import PSAConfig
     from repro.federated import run_algorithm
 
+    family = families.load(config)
     sub = tr.sub_seeds(seed)
-    world = tr.make_world(config, traffic, seed)
-    w0 = reference.init_weights(config, seed)
-    clients, test = program_inputs(world)
+    world = family.make_world(config, traffic, seed)
+    w0 = family.init_weights(config, seed)
+    clients, test = family.program_inputs(world)
     sim = sim_config(traffic, sub["shuffle"])
     t_world = time.perf_counter()
 
@@ -234,7 +227,7 @@ def run_cell(cell: dict, seed: int, seconds: float, trace: bool, *,
     probe = probe_lib.Probe(traffic, window, spans=trace,
                             on_window_start=start, on_window_end=stop)
     try:
-        planted = (faults.plant(fault) if fault is not None
+        planted = (faults.plant(fault, cfg.family) if fault is not None
                    else contextlib.nullcontext())
         with planted, probe:
             for done in (probe_lib.WarmPassDone, probe_lib.WindowClosed):
@@ -299,7 +292,7 @@ def run_cell(cell: dict, seed: int, seconds: float, trace: bool, *,
             tr_data = tracing.load(tracing.xplane_path(trace_dir))
             t0, t1 = tr_data.window()
             ctx = Context(config=config, traffic=traffic, peaks=peaks,
-                          params=flops.params(config), t_start=probe.t_start,
+                          params=flops.trained_params(config), t_start=probe.t_start,
                           t_end=probe.t_end, window_s=probe.window_s,
                           counters=counters, spans=spans,
                           compile_setup_s=compile_setup,

@@ -11,7 +11,8 @@ import sys
 import numpy as np
 import pytest
 
-from bench import correct, flops, peaks, reference, run, tracing, traffic
+from bench import (correct, families, flops, peaks, reference, run, tracing,
+                   traffic)
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 BENCH = run.load_benchmark()
@@ -63,9 +64,10 @@ def test_forward_flops_match_xla(name):
     cost = jax.jit(lambda p, x: model_lib.cnn_forward(p, x, cfg)).lower(
         params, x).compile().cost_analysis()
     cost = cost[0] if isinstance(cost, list) else cost
-    ours = flops.forward_flops(config)
+    fam = families.load(config)
+    ours = fam.forward_flops(config)
     assert ours <= cost["flops"] <= ours * 1.01
-    assert flops.params(config) == sum(
+    assert fam.params(config) == sum(
         int(l.size) for l in jax.tree_util.tree_leaves(params))
 
 
@@ -144,8 +146,10 @@ def test_trace_reduction_by_hand():
 def test_useful_step_counter_by_hand():
     """The probe's per-wave count on a small engine: clients of 20, 70
     and 140 samples, one epoch at batch 32, run 1, 2 and 4 steps (20 at
-    its own batch of 20, the others drop their last partial batch); a
-    wave of clients 0 and 2 fills a 4-row bucket of the 4-step schedule."""
+    its own batch of 20, the others drop their last partial batch). A wave
+    of clients 0 and 1 fills a 4-row bucket and runs its longest member's
+    2 steps, the trip count the program records, not the engine's 4: 1 + 2
+    real member-steps of 4 x 2 executed."""
     import jax
     from repro.common import tree as tu
     from repro.data.loader import ClientDataset, StackedClients
@@ -163,15 +167,16 @@ def test_useful_step_counter_by_hand():
     spec = tu.FlatSpec(params)
     engine = CohortEngine(cfg, StackedClients.from_datasets(clients), spec,
                           params, local_epochs=1, batch_size=32)
+    assert int(engine.num_steps) == 4
     probe = Probe({"prefix_updates": 1, "rate_hint": 1.0},
                   1.0, spans=True)
     probe.state = "window"
     w = jax.numpy.stack([spec.flatten(params)] * 2)
-    probe._cohort(engine.cohort_update, engine, w, [0, 2], [0.01] * 2, [1, 2])
+    probe._cohort(engine.cohort_update, engine, w, [0, 1], [0.01] * 2, [1, 2])
     wave = probe.waves[-1]
     assert (wave["members"], wave["rows"]) == (2, 4)
-    assert (wave["useful_steps"], wave["executed_steps"]) == (1 + 4, 4 * 4)
-    assert wave["samples"] == 1 * 20 + 4 * 32
+    assert (wave["useful_steps"], wave["executed_steps"]) == (1 + 2, 4 * 2)
+    assert wave["samples"] == 1 * 20 + 2 * 32
 
 
 def test_run_refuses_without_a_chip():
@@ -188,13 +193,17 @@ def test_run_refuses_without_a_chip():
 
 
 def test_world_keeps_its_sizes_across_seeds():
-    """Every seed gets the same set of client sizes, in another order."""
+    """Every seed gets the same client sizes, client c holding the split's
+    share c; the seed draws the classes and the samples."""
     config = traffic.load_json("configs", "paper-cifar10-cnn")
     mix = dict(traffic.load_json("workloads", "dir0.1"), samples=5000)
     a = traffic.make_world(config, mix, 1)
     b = traffic.make_world(config, mix, 2**33 + 5)
-    assert sorted(a.sizes) == sorted(b.sizes)
-    assert not np.array_equal(a.sizes, b.sizes)
+    assert np.array_equal(a.sizes, b.sizes)
+    split = traffic.client_class_counts(4500, 10, mix["clients"],
+                                        mix["alpha"], mix["partition_seed"])
+    assert np.array_equal(a.sizes, split.sum(axis=1))
+    assert not np.array_equal(a.y_train, b.y_train)
     assert a.x_train.shape == b.x_train.shape == (4500, 32, 32, 3)
     assert np.bincount(a.y_train, minlength=10).sum() == 4500
 

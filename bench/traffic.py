@@ -1,29 +1,27 @@
-"""The one traffic generator: a cell's federated world, made from the seed.
+"""The one traffic generator: what every family's world shares.
 
 A traffic mix is a JSON file under ``bench/workloads/``; a configuration is
-a JSON file under ``bench/configs/``. This module reads both and makes the
-world a run trains on: every client's labelled images, the held-out test
-set and the shared calibration batch. It imports nothing of the program.
-The calibration batch is fixed (the server broadcasts one batch of noise
-to every run), so the programs that close over it compile once and come
-from the persistent compilation cache in every later run.
+a JSON file under ``bench/configs/``. The configuration's family module
+(``bench.families``) makes the world a run trains on from both: every
+client's samples, the held-out test set and the shared calibration batch,
+in a ``World``. This module holds what does not depend on the model, and
+imports nothing of the program outside ``program_inputs``:
 
-The generator is a copy of the program's synthetic stand-in for CIFAR-10
-and MNIST (``repro.data.synthetic.make_classification``: a Gaussian mixture
-with one mean per class and a mild class-dependent rotation, at
-``class_sep=0.7`` as ``repro.launch.train.build_task`` uses) and of its
-Dirichlet label-skew split (``repro.data.partition.dirichlet_partition``).
-Two things differ, and neither changes the distribution:
+- the run's independent seed streams (``sub_seeds``);
+- the split's structure, drawn from the mix's fixed ``partition_seed``
+  (``client_class_counts``, a copy of the program's Dirichlet label-skew
+  split ``repro.data.partition.dirichlet_partition``; token families deal
+  documents, ``bench.token_world``). Client c holds the split's share c on
+  every seed, so every seed has the same client sizes, the same padded
+  slab, the same waves on the mix's fixed timeline and the same compiled
+  shapes; the seed draws every sample, the weights and the batch
+  shuffles;
+- a client's batch schedule (``epoch_batch_indices``);
+- the program's client datasets over a world (``program_inputs``).
 
-- samples are drawn class by class, so the per-sample rotation is one
-  (n_k, dim) x (dim, 8) product per class, not the (samples, dim, 8)
-  gather of the original (4.9 GB at 50,000 CIFAR-sized samples);
-- the split's structure (how many samples of each class each client holds)
-  comes from the mix's fixed ``partition_seed``. The run's seed permutes
-  which client holds which share and which class is which, and draws every
-  sample, the weights and the batch shuffles. So every seed has the same
-  set of client sizes, the same padded slab and the same compiled shapes,
-  in another order.
+The calibration batch is fixed (the server broadcasts one batch to every
+run), so the programs that close over it compile once and come from the
+persistent compilation cache in every later run.
 """
 from __future__ import annotations
 
@@ -36,12 +34,9 @@ import numpy as np
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 
-# The program's generator constants (repro.data.synthetic, build_task).
-CLASS_SEP = 0.7
-NOISE = 1.0
-ROTATION_DIMS = 8
+# The program's constants (repro.launch.train.build_task,
+# repro.data.calibration).
 TEST_FRACTION = 0.1
-CALIB_BATCH = 64
 CALIB_SEED = 123
 MIN_CLIENT_SIZE = 2
 
@@ -100,9 +95,10 @@ def client_class_counts(n_train: int, num_classes: int, num_clients: int,
 @dataclass
 class World:
     """A cell's data. ``x_train`` is client-major: client c holds rows
-    ``offsets[c]:offsets[c + 1]``."""
-    x_train: np.ndarray      # (n_train, H, W, C) float32
-    y_train: np.ndarray      # (n_train,) int64
+    ``offsets[c]:offsets[c + 1]``. ``calib`` is keyed by the family's
+    ``BATCH_KEYS``."""
+    x_train: np.ndarray      # (n_train, ...) features or token sequences
+    y_train: np.ndarray      # (n_train, ...) labels
     offsets: np.ndarray      # (clients + 1,) int64
     x_test: np.ndarray
     y_test: np.ndarray
@@ -118,64 +114,23 @@ class World:
         return self.x_train[lo:hi], self.y_train[lo:hi]
 
 
-class _Mixture:
-    """The class structure: one mean per class on a sphere of radius
-    ``CLASS_SEP`` and a (dim, 8) rotation per class."""
-
-    def __init__(self, rng: np.random.Generator, num_classes: int, dim: int):
-        means = rng.standard_normal((num_classes, dim), np.float32)
-        self.means = means * (CLASS_SEP / np.linalg.norm(
-            means, axis=1, keepdims=True))
-        self.rot = (rng.standard_normal((num_classes, dim, ROTATION_DIMS),
-                                        np.float32) / np.sqrt(dim))
-
-    def fill(self, rng: np.random.Generator, out: np.ndarray,
-             labels: np.ndarray) -> None:
-        """Draw one sample per label into the rows of ``out`` (n, dim)."""
-        for k in np.unique(labels):
-            rows = np.nonzero(labels == k)[0]
-            x = rng.standard_normal((rows.size, out.shape[1]), np.float32)
-            x *= np.float32(0.3 * NOISE)
-            x += self.means[k]
-            x[:, :ROTATION_DIMS] += 0.5 * np.tanh(x @ self.rot[k])
-            out[rows] = x
-
-
 def make_world(config: dict, traffic: dict, seed: int) -> World:
-    """The world of one run: the mix's split structure, permuted and filled
-    with samples drawn from ``seed``."""
-    hw = tuple(config["input_hw"])
-    dim = int(np.prod(hw))
-    K = int(config["num_classes"])
-    C = int(traffic["clients"])
-    n_total = int(traffic["samples"])
-    n_test = int(n_total * TEST_FRACTION)
-    n_train = n_total - n_test
-    counts = client_class_counts(n_train, K, C, float(traffic["alpha"]),
-                                 int(traffic["partition_seed"]))
-    s = sub_seeds(seed)
-    rng = np.random.default_rng(s["data"])
-    counts = counts[rng.permutation(C)][:, rng.permutation(K)]
-    sizes = counts.sum(axis=1)
-    offsets = np.concatenate([[0], np.cumsum(sizes)]).astype(np.int64)
-    # client-major labels, each client's samples grouped by class
-    y_train = np.repeat(np.tile(np.arange(K), C), counts.reshape(-1))
-    mix = _Mixture(rng, K, dim)
-    x_train = np.empty((n_train, dim), np.float32)
-    mix.fill(rng, x_train, y_train)
-    y_test = rng.integers(0, K, n_test)
-    x_test = np.empty((n_test, dim), np.float32)
-    mix.fill(rng, x_test, y_test)
-    # the shared calibration batch is the protocol's, not the seed's:
-    # Gaussian noise with uniform labels from a fixed RandomState(123), as
-    # repro.data.calibration draws it (FedPSA Table 5)
-    crng = np.random.RandomState(CALIB_SEED)
-    calib = {"x": crng.randn(CALIB_BATCH, *hw).astype(np.float32),
-             "y": crng.randint(0, K, size=CALIB_BATCH).astype(np.int32)}
-    return World(x_train=x_train.reshape((n_train,) + hw),
-                 y_train=y_train.astype(np.int64), offsets=offsets,
-                 x_test=x_test.reshape((n_test,) + hw),
-                 y_test=y_test.astype(np.int64), calib=calib, num_classes=K)
+    """The world of one run, made by the configuration's family module."""
+    from bench import families
+    return families.load(config).make_world(config, traffic, seed)
+
+
+def program_inputs(world: World):
+    """The program's client datasets and test set over a world's arrays,
+    one ``ClientDataset`` per client; the only place the benchmark's data
+    side imports the program."""
+    from repro.data.loader import ClientDataset
+    from repro.data.synthetic import SyntheticClassification
+    K = world.num_classes
+    clients = [ClientDataset(SyntheticClassification(*world.client(c), K))
+               for c in range(len(world.sizes))]
+    test = SyntheticClassification(world.x_test, world.y_test, K)
+    return clients, test
 
 
 def epoch_batch_indices(n: int, num_epochs: int, batch_size: int,
