@@ -192,20 +192,50 @@ def test_run_refuses_without_a_chip():
     assert "no TPU" in proc.stderr
 
 
-def test_world_keeps_its_sizes_across_seeds():
+# the mixes of the cells and of the cross-device cell left out of
+# BENCHMARK.json, each at a tenth of its samples (crossdevice at a tenth of
+# its clients too, so that a client still holds about 54)
+WORLDS = [("dir0.1", "paper-cifar10-cnn", {"samples": 5000}),
+          ("iid", "paper-cifar10-cnn", {"samples": 5000}),
+          ("crossdevice", "paper-mnist-cnn", {"samples": 6000,
+                                              "clients": 100})]
+
+
+@pytest.mark.parametrize("mix_name,config_name,over", WORLDS,
+                         ids=[w[0] for w in WORLDS])
+def test_world_keeps_its_sizes_across_seeds(mix_name, config_name, over):
     """Every seed gets the same client sizes, client c holding the split's
     share c; the seed draws the classes and the samples."""
-    config = traffic.load_json("configs", "paper-cifar10-cnn")
-    mix = dict(traffic.load_json("workloads", "dir0.1"), samples=5000)
+    config = traffic.load_json("configs", config_name)
+    mix = dict(traffic.load_json("workloads", mix_name), **over)
     a = traffic.make_world(config, mix, 1)
     b = traffic.make_world(config, mix, 2**33 + 5)
+    n_train = mix["samples"] - int(mix["samples"] * traffic.TEST_FRACTION)
     assert np.array_equal(a.sizes, b.sizes)
-    split = traffic.client_class_counts(4500, 10, mix["clients"],
+    split = traffic.client_class_counts(n_train, 10, mix["clients"],
                                         mix["alpha"], mix["partition_seed"])
     assert np.array_equal(a.sizes, split.sum(axis=1))
     assert not np.array_equal(a.y_train, b.y_train)
-    assert a.x_train.shape == b.x_train.shape == (4500, 32, 32, 3)
-    assert np.bincount(a.y_train, minlength=10).sum() == 4500
+    assert a.x_train.shape == b.x_train.shape == (
+        (n_train,) + tuple(config["input_hw"]))
+    assert np.bincount(a.y_train, minlength=10).sum() == n_train
+
+
+def test_iid_clients_hold_equal_shares():
+    """The IID mix at its full size: every client holds the same number of
+    samples, so every client runs the same local steps and no wave pads a
+    step; dir0.1's split, the same protocol, does not."""
+    for name, equal in (("iid", True), ("dir0.1", False)):
+        mix = traffic.load_json("workloads", name)
+        n_train = mix["samples"] - int(mix["samples"] * traffic.TEST_FRACTION)
+        sizes = traffic.client_class_counts(
+            n_train, 10, mix["clients"], mix["alpha"],
+            mix["partition_seed"]).sum(axis=1)
+        assert sizes.sum() == n_train
+        assert (len(set(sizes.tolist())) == 1) == equal, (name, sizes)
+        steps = mix["local_epochs"] * (sizes // np.minimum(mix["batch"],
+                                                           sizes))
+        assert (len(set(steps.tolist())) == 1) == equal
 
 
 TINY = {"name": "tiny-cnn", "program_config": "paper-mnist-cnn",
