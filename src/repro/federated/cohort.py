@@ -56,15 +56,18 @@ def bucket_size(B: int, data_kind: str = "tokens") -> int:
     are masked no-ops but still execute their local steps, so the grid
     trades padded compute against compiled-program count:
 
-    ``image`` — multiples of 4 (max_cohort/4 programs, <= 3 wasted rows):
-    the cnn/mlp programs compile in milliseconds, so a dense grid is free.
+    ``image`` — {1, 4, 8, 12, ...}: a lone member trains in one row, and
+    B >= 2 rounds up to a multiple of 4 (<= 3 wasted rows): the cnn/mlp
+    programs compile in about a second, so a dense grid is cheap, and the
+    step's activation passes scale with rows, so the lone member (most
+    waves when few clients are in flight) stops training three zero rows.
 
     ``tokens`` — {4, 6, 8, 12, 16, 24, 32, ...} (powers of two and 1.5x
     powers of two; worst-case 1.5x padded compute, O(log max_cohort)
     programs): transformer-family programs compile in *seconds* each, so a
     dense grid would stall mid-run on every fresh wave size."""
     if data_kind == "image":
-        return -(-B // 4) * 4
+        return 1 if B == 1 else -(-B // 4) * 4
     if B <= 4:
         return 4
     p = 1 << (B - 1).bit_length()          # next power of two >= B

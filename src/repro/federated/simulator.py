@@ -906,6 +906,9 @@ def _drain_cohort(server, cfg, init_params, client_datasets, sim: SimConfig,
                          for r in range(len(ok_events))]
                 deltas, w_stack = engine.cohort_update(snapshots, cids, lrs,
                                                        seeds)
+                # the trained wave no longer needs its dispatch snapshots:
+                # free them before the sketch's temporaries are allocated
+                del snapshots
                 if sketch_flat is not None:
                     with obs.span("sketch.enqueue"):
                         sketches = sketch_flat(w_stack)

@@ -151,13 +151,39 @@ def test_one_program_serves_every_trip_count(ragged, monkeypatch):
     monkeypatch.setattr(eng, "_run", run)
     longest = int(np.argmax(eng.steps_per_client))
     t0 = time.perf_counter()
-    for cids in ([short[0]], short[1:], short, [short[0], longest]):
+    for cids in (short[:2], short, [short[0], longest]):
         eng.cohort_update(stack[:len(cids)], cids, [0.01] * len(cids),
                           list(range(len(cids))))
         assert run._cache_size() == 1
     waves = obs.records("cohort.wave", t0, time.perf_counter())
     assert {w["rows"] for w in waves} == {4}
     assert len({w["schedule"] for w in waves}) >= 3
+
+
+def test_lone_member_trains_in_one_row(ragged, world):
+    """A wave of one member runs one row, and its cohort step and sketch
+    give what that member gets inside a 4-row wave."""
+    from repro.common import obs
+    from repro.core import PSAConfig
+    from repro.data import make_calibration_batch
+    from repro.federated import simulator
+    cfg, _, _, spec, eng, short, stack = ragged
+    calib = make_calibration_batch(world[1], 64, "gaussian")
+    sketch = simulator._build_sketch_fn_flat(cfg, calib, PSAConfig(), spec)
+    lrs, seeds = [0.01, 0.008, 0.012], [5, 6, 7]
+    d_wave, w_wave = eng.cohort_update(stack, short, lrs, seeds)
+    s_wave = sketch(w_wave)
+    t0 = time.perf_counter()
+    d_lone, w_lone = eng.cohort_update(stack[:1], short[:1], lrs[:1],
+                                       seeds[:1])
+    s_lone = sketch(w_lone)
+    t1 = time.perf_counter()
+    assert [w["rows"] for w in obs.records("cohort.wave", t0, t1)] == [1]
+    assert [r["rows"] for r in obs.records("sketch.rows", t0, t1)] == [1]
+    for lone, wave in ((d_lone, d_wave), (w_lone, w_wave), (s_lone, s_wave)):
+        assert lone.shape[0] == 1
+        np.testing.assert_allclose(np.asarray(lone[0]), np.asarray(wave[0]),
+                                   rtol=1e-6)
 
 
 def test_sweep_and_streaming_match_cohort_on_a_short_wave(ragged):

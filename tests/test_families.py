@@ -93,11 +93,12 @@ def test_resolve_engine_consults_registry():
 
 def test_bucket_size_grid():
     # token families: coarse {pow2, 1.5*pow2} grid (compile cost is seconds
-    # per program), image families: the legacy fine multiples-of-4 grid
+    # per program), image families: a lone member in one row, else the fine
+    # multiples-of-4 grid
     assert [bucket_size(b) for b in (1, 4, 5, 6, 7, 9, 13, 17, 25, 33)] == \
         [4, 4, 6, 6, 8, 12, 16, 24, 32, 48]
-    assert [bucket_size(b, "image") for b in (1, 4, 5, 9, 31)] == \
-        [4, 4, 8, 12, 32]
+    assert [bucket_size(b, "image") for b in (1, 2, 4, 5, 9, 31)] == \
+        [1, 4, 4, 8, 12, 32]
     for b in range(1, 300):
         for kind in ("tokens", "image"):
             assert b <= bucket_size(b, kind) <= max(4, (3 * b + 1) // 2)
@@ -108,6 +109,7 @@ def test_wave_axis_splits_only_divisible_buckets():
     mesh = types.SimpleNamespace(shape={"data": 4})
     assert wave_axis(mesh, "data", 8) == "data"
     assert wave_axis(mesh, "data", 6) is None
+    assert wave_axis(mesh, "data", bucket_size(1, "image")) is None
     assert wave_axis(mesh, None, 8) is None
     assert wave_axis(None, None, 4) is None
 

@@ -92,12 +92,15 @@ def test_grouped_matmul_compiles(one_chip):
                        (G,))
 
 
-@pytest.mark.parametrize("axis", ["d", None])
-def test_wave_sketch_compiles_on_mesh(v5e_2x2, monkeypatch, axis):
+@pytest.mark.parametrize("axis,rows", [
+    pytest.param("d", 4, id="d"), pytest.param(None, 4, id="None"),
+    pytest.param(None, 1, id="lone")])
+def test_wave_sketch_compiles_on_mesh(v5e_2x2, monkeypatch, axis, rows):
     """The cohort engine's wave sketch on a four-chip mesh: a Mosaic kernel
     is never partitioned automatically, so the batched sketch must hold it
     inside ``shard_map`` — over the wave's sharded cohort axis, or
-    replicated when the bucket does not divide the mesh."""
+    replicated when the bucket does not divide the mesh (a lone member's
+    1-row wave among them)."""
     import numpy as np
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
@@ -118,6 +121,6 @@ def test_wave_sketch_compiles_on_mesh(v5e_2x2, monkeypatch, axis):
              "y": np.zeros((8,), np.int32)}
     fn = simulator._build_sketch_fn_flat(cfg, calib, PSAConfig(), spec,
                                          mesh, "d")
-    w = jax.ShapeDtypeStruct((4, spec.size), jnp.float32,
+    w = jax.ShapeDtypeStruct((rows, spec.size), jnp.float32,
                              sharding=NamedSharding(mesh, P(axis)))
     assert "tpu_custom_call" in jax.jit(fn).lower(w).compile().as_text()
